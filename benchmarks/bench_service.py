@@ -1,6 +1,7 @@
 """Placement service + portfolio + transfer + scheduler -> BENCH_placement.json.
 
     PYTHONPATH=src python -m benchmarks.bench_service [--smoke|--full] [--out P]
+    PYTHONPATH=src python -m benchmarks.compile_probe --pair --merge-into P
 
 The serving-perf trajectory, one JSON per run.  Four measurements:
 
@@ -67,19 +68,20 @@ The serving-perf trajectory, one JSON per run.  Four measurements:
     `job.submit` and one terminal event per job) is a hard CI gate.
 
   * **compile**: cold-start latency vs the persistent compilation cache
-    (`runtime.compile_cache`).  Two fresh subprocesses
-    (`benchmarks.compile_probe`) share one cache directory: the first
-    (cold) populates it, the second (warm) must deserialize instead of
-    recompiling.  `recompiles_warm_zero` (the warm probe performed ZERO
-    real XLA compiles) and `warm_ttfg_5x` (warm time-to-first-generation
-    is >= 5x faster than cold) are hard CI gates; the raw
-    cold/warm ttfg and compile counts are trend keys.
+    (`runtime.compile_cache`).  This process holds the device, so it
+    cannot start the probe processes that measure a cold start: the
+    section comes from its own command, `benchmarks.compile_probe --pair
+    --merge-into P`, run after this one.  `recompiles_warm_zero` (the
+    warm probe performed ZERO real XLA compiles) and `warm_ttfg_5x` (warm
+    time-to-first-generation is >= 5x faster than cold) are hard CI
+    gates; the raw cold/warm ttfg and compile counts are trend keys.
 
   * **kernels**: the fused Pallas evaluation pipeline
     (`kernels.fused_eval`) vs the unfused two-op dispatch at EQUAL
     workload shape: candidate evaluations/sec for both paths (best-of-k
     jitted steady state), the fused/unfused speedup, and two differential
-    correctness gates -- the tiled kernel body (interpret mode) matching
+    correctness gates -- the tiled kernel (compiled on a TPU, interpret
+    mode elsewhere) matching
     `ref.fused_eval_ref` on the real problem extents (`fused_match_ref`)
     and the fused domination counts matching the domination matrix
     (`dom_counts_match_ref`).  Both booleans are hard CI gates; the
@@ -120,7 +122,8 @@ tooling -- keys are append-only):
   telemetry.{n_clients,n_slots,max_queue,pop_size,budget_gens,
              gens_per_step,rounds,jobs_per_sec_off,jobs_per_sec_on,
              enabled_overhead_pct,trace_events_complete},
-  compile.{pop_size,n_slots,gens_per_step,budget_gens,grow_to,cache_salt,
+  compile.{pop_size,n_slots,gens_per_step,budget_gens,grow_to,backend,
+           cache_salt,
            ttfg_cold_ms,ttfg_warm_ms,ttfg_speedup,compiles_cold,
            recompiles_cold,compile_secs_cold,compiles_warm,
            recompiles_warm,cache_hits_warm,compile_secs_warm,
@@ -139,6 +142,7 @@ from benchmarks import common
 from repro.core import evolve, nsga2, cmaes, transfer, portfolio
 from repro.core import objectives as O
 from repro.core.islands import IslandConfig
+from repro.runtime import compile_cache
 from repro.serve.api import JobRequest
 from repro.serve.champion_store import ChampionStore
 from repro.serve.placement_service import PlacementService, make_job_specs
@@ -525,11 +529,11 @@ def bench_kernels(prob, pop: int, reps: int = 40, timed_rounds: int = 12
     lower to the same XLA program (verified: identical fusion/while
     counts), so a best-sample gap below the ~3% measurement resolution is
     a tie and reports the pooled best for both paths instead of
-    coin-flipping the ordering; a genuinely different path (the TPU
-    Pallas kernel vs materialised intermediates) clears 3% trivially.
-    Correctness runs the Pallas bodies in interpret mode against the
-    `ref.py` oracles -- the same differential contract as
-    `tests/test_fused_eval.py`, here on the real decode extents.
+    coin-flipping the ordering.
+    Correctness checks the Pallas kernels against the `ref.py` oracles on
+    the real decode extents: compiled on a TPU, in interpret mode
+    elsewhere (the same differential contract as
+    `tests/test_fused_eval.py`).
     """
     import jax.numpy as jnp
 
@@ -540,17 +544,19 @@ def bench_kernels(prob, pop: int, reps: int = 40, timed_rounds: int = 12
     keys = jax.random.split(jax.random.PRNGKey(11), pop)
     popn = jax.vmap(lambda k: G.random_genotype(k, prob))(keys)
 
-    # differential gates on the real extents
+    # differential gates on the real extents: the compiled kernels on a
+    # TPU, their bodies in interpret mode elsewhere
+    interpret = jax.default_backend() != "tpu"
     bx, by = jax.vmap(lambda g: G.decode(prob, g))(popn)
     s, d = jnp.asarray(prob.net_src), jnp.asarray(prob.net_dst)
     w = jnp.asarray(prob.net_w)
     uidx = O.unit_index(prob)
     got = np.asarray(FE.fused_eval_pallas(bx, by, s, d, w, uidx,
-                                          interpret=True))
+                                          interpret=interpret))
     want = np.asarray(ref.fused_eval_ref(bx, by, s, d, w, uidx))
     fused_match_ref = bool(np.allclose(got, want, rtol=1e-5, atol=1e-6))
     objs = jnp.asarray(want)
-    dom, cnt = FE.domination_counts_pallas(objs, interpret=True)
+    dom, cnt = FE.domination_counts_pallas(objs, interpret=interpret)
     dref = np.asarray(ref.domination_ref(objs))
     dom_counts_match_ref = bool(
         np.array_equal(np.asarray(dom.astype(bool)), dref)
@@ -745,69 +751,9 @@ def bench_telemetry(dev: str, n_clients: int, n_slots: int, pop: int,
     }
 
 
-def bench_compile(cache_dir: str = None, pop: int = 16, n_slots: int = 8,
-                  gens_per_step: int = 8, budget: int = 8,
-                  device: str = "xcvu_test", grow_to: int = 16) -> dict:
-    """Cold vs cache-restored cold start, measured in fresh subprocesses.
-
-    In-memory jit caches die with a process, so each leg runs
-    `benchmarks.compile_probe` as its own interpreter against a shared
-    persistent-cache directory: leg 1 (cold) fills it, leg 2 (warm) must
-    answer every compile request from it.  With no `cache_dir` given a
-    fresh temporary directory is used (the committed-baseline mode: the
-    cold leg is deterministically cold); CI's compile-budget job passes
-    its own directory the same way.
-    """
-    import os
-    import shutil
-    import subprocess
-    import sys
-    import tempfile
-
-    fresh = cache_dir is None
-    if fresh:
-        cache_dir = tempfile.mkdtemp(prefix="repro-compile-bench-")
-
-    def leg() -> dict:
-        cmd = [sys.executable, "-m", "benchmarks.compile_probe",
-               "--cache-dir", cache_dir, "--pop", str(pop),
-               "--slots", str(n_slots), "--gps", str(gens_per_step),
-               "--budget", str(budget), "--device", device,
-               "--grow-to", str(grow_to)]
-        out = subprocess.run(cmd, check=True, capture_output=True,
-                             text=True, env=dict(os.environ))
-        # last stdout line is the JSON object (jax may log above it)
-        return json.loads(out.stdout.strip().splitlines()[-1])
-
-    try:
-        cold = leg()
-        warm = leg()
-    finally:
-        if fresh:
-            shutil.rmtree(cache_dir, ignore_errors=True)
-    speedup = cold["ttfg_ms"] / max(warm["ttfg_ms"], 1e-9)
-    return {
-        "pop_size": pop, "n_slots": n_slots, "gens_per_step": gens_per_step,
-        "budget_gens": budget, "grow_to": grow_to, "device": device,
-        "cache_salt": cold["cache_salt"],
-        "ttfg_cold_ms": cold["ttfg_ms"],
-        "ttfg_warm_ms": warm["ttfg_ms"],
-        "ttfg_speedup": round(speedup, 2),
-        "compiles_cold": cold["compiles"],
-        "recompiles_cold": cold["recompiles"],
-        "compile_secs_cold": cold["compile_secs"],
-        "compiles_warm": warm["compiles"],
-        "recompiles_warm": warm["recompiles"],
-        "cache_hits_warm": warm["cache_hits"],
-        "compile_secs_warm": warm["compile_secs"],
-        "recompiles_warm_zero": bool(warm["recompiles"] == 0),
-        "warm_ttfg_5x": bool(speedup >= 5.0),
-    }
-
-
-def main(out: str = "BENCH_placement.json", mode: str = "quick",
-         compile_cache_dir: str = None) -> dict:
+def main(out: str = "BENCH_placement.json", mode: str = "quick") -> dict:
     """mode: smoke (CI PR gate) < quick (default) < full (paper-scale)."""
+    compile_cache.enable()
     smoke, full = mode == "smoke", mode == "full"
     dev = "xcvu11p" if full else "xcvu_test"
     prob = common.problem(dev)
@@ -868,10 +814,6 @@ def main(out: str = "BENCH_placement.json", mode: str = "quick",
     te = bench_telemetry(
         dev, n_clients=16, n_slots=8, pop=16,
         budget=8 if not full else 16, gens_per_step=4, max_queue=16)
-    # shapes deliberately do NOT scale with mode: the compile bill depends
-    # on the program set, not the budgets, and a fixed shape keeps the
-    # cold/warm numbers comparable across smoke / quick / full reports
-    comp = bench_compile(cache_dir=compile_cache_dir)
     report = {
         "bench": "placement_service",
         "created_unix": int(time.time()),
@@ -890,13 +832,13 @@ def main(out: str = "BENCH_placement.json", mode: str = "quick",
         "kernels": kern,
         "frontend": fe,
         "telemetry": te,
-        "compile": comp,
     }
     with open(out, "w") as f:
         json.dump(report, f, indent=2)
         f.write("\n")
     print(json.dumps(report, indent=2))
-    print(f"\nwrote {out}")
+    print(f"\nwrote {out}; add the compile section with: python -m "
+          f"benchmarks.compile_probe --pair --merge-into {out}")
     return report
 
 
@@ -906,13 +848,8 @@ if __name__ == "__main__":
                     help="smallest budgets (CI PR gate)")
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--out", default="BENCH_placement.json")
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persistent-cache directory for the compile "
-                         "section's probe pair (default: a fresh temp dir, "
-                         "so the cold leg is deterministically cold)")
     args = ap.parse_args()
     if args.smoke and args.full:
         ap.error("--smoke and --full are mutually exclusive")
     main(out=args.out,
-         mode="smoke" if args.smoke else ("full" if args.full else "quick"),
-         compile_cache_dir=args.compile_cache_dir)
+         mode="smoke" if args.smoke else ("full" if args.full else "quick"))

@@ -1,16 +1,17 @@
-"""One cold-start measurement in a fresh process -> one JSON line.
+"""Cold-start measurement in a fresh process, and the cold/warm pair.
 
-    PYTHONPATH=src python -m benchmarks.compile_probe --cache-dir D [...]
+    PYTHONPATH=src python -m benchmarks.compile_probe [--out P]
+    PYTHONPATH=src python -m benchmarks.compile_probe --pair \
+        [--merge-into BENCH_placement.json]
 
-The compile bench (`bench_service.bench_compile`) and the CI
-`compile-budget` job both need the SAME measurement twice: "how long does
-a fresh process take to serve its first generation, and how many real XLA
-compiles did that cost?"  Cold vs warm is decided entirely by what is in
-`--cache-dir` when the probe starts -- an empty directory gives the cold
-number, a directory populated by a previous probe (or restored by CI's
-`actions/cache`) gives the cache-restored number.  Running the probe as a
-subprocess is the point: jax's in-memory jit caches die with the process,
-so only the persistent compilation cache can make the second run fast.
+One probe answers "how long does a fresh process take to serve its first
+generation, and how many real XLA compiles did that cost?".  It turns on
+the persistent compilation cache where `runtime.compile_cache.cache_dir()`
+says (`$JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`); cold
+vs warm is decided entirely by what that directory holds when the probe
+starts.  Running the probe as its own process is the point: jax's
+in-memory jit caches die with the process, so only the persistent cache can
+make a second run fast.
 
 The probe builds a smoke-shaped `PlacementService`, runs one job through
 its first batched step, then exercises one `grow()` ladder rung -- the
@@ -23,19 +24,32 @@ single JSON object:
 
 `recompiles` (real XLA compiles: requests the persistent cache did not
 answer) is the number the CI budget pins at 0 for a warm start.
+
+`--pair` is the bench's `compile` section.  It clears the fixed
+subdirectory `<cache_dir()>/compile_probe`, then runs two probes against
+it, cold then warm, each in its own process, and prints the section
+(`--merge-into` also writes it into a bench report under "compile").  The
+pair's parent never imports jax: a process that holds JAX holds the chip,
+and a child that needs the chip would then fail or hang.  So run the pair
+as its own command, never from a process that has touched JAX.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
+import shutil
+import subprocess
 import sys
 import time
 
+PAIR_SUBDIR = "compile_probe"
 
-def probe(cache_dir: str, pop: int, n_slots: int, gens_per_step: int,
-          budget: int, device: str, grow_to: int) -> dict:
+
+def probe(pop: int, n_slots: int, gens_per_step: int, budget: int,
+          device: str, grow_to: int) -> dict:
     from repro.runtime import compile_cache
-    compile_cache.enable(cache_dir)
+    cache_dir = compile_cache.enable()
     m = compile_cache.meter().install()
 
     import jax
@@ -75,11 +89,64 @@ def probe(cache_dir: str, pop: int, n_slots: int, gens_per_step: int,
     }
 
 
+def pair(pop: int = 16, n_slots: int = 8, gens_per_step: int = 8,
+         budget: int = 8, device: str = "xcvu_test",
+         grow_to: int = 16) -> dict:
+    """Cold vs cache-restored cold start: two probe processes sharing one
+    freshly cleared cache directory.  Returns the bench `compile` section.
+
+    Leg 1 (cold) fills the directory, leg 2 (warm) must answer every
+    compile request from it.  Shapes do not scale with the bench mode: the
+    compile bill depends on the program set, not the budgets."""
+    if "jax" in sys.modules:
+        raise RuntimeError("the probe pair's parent must not import jax: "
+                           "its children need the device")
+    from repro.runtime.compile_cache import cache_dir
+    root = os.path.join(cache_dir(), PAIR_SUBDIR)
+    shutil.rmtree(root, ignore_errors=True)
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=root)
+
+    def leg() -> dict:
+        cmd = [sys.executable, "-m", "benchmarks.compile_probe",
+               "--pop", str(pop), "--slots", str(n_slots),
+               "--gps", str(gens_per_step), "--budget", str(budget),
+               "--device", device, "--grow-to", str(grow_to)]
+        out = subprocess.run(cmd, check=True, capture_output=True,
+                             text=True, env=env)
+        # last stdout line is the JSON object (jax may log above it)
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    cold = leg()
+    warm = leg()
+    speedup = cold["ttfg_ms"] / max(warm["ttfg_ms"], 1e-9)
+    return {
+        "pop_size": pop, "n_slots": n_slots, "gens_per_step": gens_per_step,
+        "budget_gens": budget, "grow_to": grow_to, "device": device,
+        "backend": cold["backend"],
+        "cache_salt": cold["cache_salt"],
+        "ttfg_cold_ms": cold["ttfg_ms"],
+        "ttfg_warm_ms": warm["ttfg_ms"],
+        "ttfg_speedup": round(speedup, 2),
+        "compiles_cold": cold["compiles"],
+        "recompiles_cold": cold["recompiles"],
+        "compile_secs_cold": cold["compile_secs"],
+        "compiles_warm": warm["compiles"],
+        "recompiles_warm": warm["recompiles"],
+        "cache_hits_warm": warm["cache_hits"],
+        "compile_secs_warm": warm["compile_secs"],
+        "recompiles_warm_zero": bool(warm["recompiles"] == 0),
+        "warm_ttfg_5x": bool(speedup >= 5.0),
+    }
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--cache-dir", required=True,
-                    help="persistent compilation cache directory (empty = "
-                         "cold measurement, populated = warm)")
+    ap.add_argument("--pair", action="store_true",
+                    help="run a cold and a warm probe in child processes "
+                         "and print the bench `compile` section")
+    ap.add_argument("--merge-into", default=None, metavar="REPORT",
+                    help="with --pair, also write the section into this "
+                         "bench JSON report under \"compile\"")
     ap.add_argument("--pop", type=int, default=16)
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--gps", type=int, default=8)
@@ -91,8 +158,19 @@ def main() -> int:
     ap.add_argument("--out", default=None,
                     help="also write the JSON object to this path")
     args = ap.parse_args()
-    out = probe(args.cache_dir, args.pop, args.slots, args.gps,
-                args.budget, args.device, args.grow_to)
+    shape = (args.pop, args.slots, args.gps, args.budget, args.device,
+             args.grow_to)
+    if args.pair:
+        out = pair(*shape)
+        if args.merge_into:
+            with open(args.merge_into) as f:
+                report = json.load(f)
+            report["compile"] = out
+            with open(args.merge_into, "w") as f:
+                json.dump(report, f, indent=2)
+                f.write("\n")
+    else:
+        out = probe(*shape)
     line = json.dumps(out)
     print(line)
     if args.out:

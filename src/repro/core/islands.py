@@ -288,23 +288,24 @@ def _run(problem: Problem, algo: str, cfg, icfg: IslandConfig,
     keys = island_keys(key, icfg.n_islands)
     halves = jax.vmap(jax.random.split)(keys)               # [P, 2, key]
     k_init, k_run = halves[:, 0], halves[:, 1]
-    states = jax.vmap(lambda k: m.init_state(problem, k, cfg))(k_init)
     gen_keys = jnp.swapaxes(
         jax.vmap(lambda k: jax.random.split(k, n_gens))(k_run), 0, 1)
 
-    if mesh is None:
-        return round_impl(problem, algo, icfg, cfg, states, gen_keys,
-                          jnp.int32(0))
+    def init_and_run(ki, gk, axis=None):
+        states = jax.vmap(lambda k: m.init_state(problem, k, cfg))(ki)
+        return round_impl(problem, algo, icfg, cfg, states, gk,
+                          jnp.int32(0), axis=axis)
 
-    @functools.partial(
-        jc.shard_map, mesh=mesh,
+    if mesh is None:
+        return init_and_run(k_init, gen_keys)
+
+    # the init evaluates the population too, so it runs inside the
+    # shard_map: the TPU's Pallas kernels cannot be auto-partitioned
+    sharded = jc.shard_map(
+        functools.partial(init_and_run, axis=AXIS), mesh=mesh,
         in_specs=(P(AXIS), P(None, AXIS)),
         out_specs=(P(AXIS), P(None, AXIS)))
-    def sharded(st, gk):
-        return round_impl(problem, algo, icfg, cfg, st, gk,
-                          jnp.int32(0), axis=AXIS)
-
-    return sharded(states, gen_keys)
+    return sharded(k_init, gen_keys)
 
 
 def run(problem: Problem, algo: str, cfg, key: jax.Array, n_gens: int,

@@ -38,8 +38,8 @@ def objectives_from_coords(problem: Problem, bx: jnp.ndarray, by: jnp.ndarray,
     """(wirelength^2, max bbox) from logical block coordinates [..., G].
 
     `fused=False` (default) is the original two-op path, bit-for-bit;
-    `fused=True` routes through `ops.fused_eval` -- one kernel, no
-    materialised endpoint/unit tensors between the objectives.
+    `fused=True` routes through `ops.fused_eval` -- one kernel launch
+    reduces both objectives.
     """
     s, d = jnp.asarray(problem.net_src), jnp.asarray(problem.net_dst)
     w = jnp.asarray(problem.net_w)

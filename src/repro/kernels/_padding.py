@@ -66,17 +66,12 @@ def pad_net_endpoints(x1, y1, x2, y2, w, bn: int
             pad_multiple(w, -1, bn))
 
 
-def pad_net_indices(src, dst, w, bn: int, n_tiles: int = 0
+def pad_net_indices(src, dst, w, bn: int
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pad gather-index nets for the fused layout: indices to gid 0 (any
-    in-range gid is safe), weights to 0 (the neutrality guarantee).
-
-    `n_tiles` (optional) forces at least that many bn-tiles so the net
-    grid can share an axis with the unit grid (`fused_eval`)."""
-    n = src.shape[-1]
-    total = max(-(-n // bn), n_tiles) * bn
-    return (pad_multiple(src, -1, total), pad_multiple(dst, -1, total),
-            pad_multiple(w, -1, total))
+    in-range gid is safe), weights to 0 (the neutrality guarantee)."""
+    return (pad_multiple(src, -1, bn), pad_multiple(dst, -1, bn),
+            pad_multiple(w, -1, bn))
 
 
 def pad_unit_blocks(ux, uy, bb: int, bu: int
@@ -92,23 +87,16 @@ def pad_unit_blocks(ux, uy, bb: int, bu: int
     return ux, uy
 
 
-def pad_unit_index(uidx: jnp.ndarray, bu: int, bb: int = 8,
-                   n_tiles: int = 0) -> jnp.ndarray:
+def pad_unit_index(uidx: jnp.ndarray, bu: int, bb: int = 8) -> jnp.ndarray:
     """Pad a [U, B] unit gather table for the fused layout.
 
     Padded blocks (axis 1) replicate the unit's last block (duplicate
     coordinates never move a min/max); padded units (axis 0) point every
     block at gid 0 -- a degenerate unit whose bbox is exactly 0, neutral
-    under the final max because every real bbox is >= 0.  `n_tiles`
-    forces at least that many bu-tiles (shared grid with the net axis).
+    under the final max because every real bbox is >= 0.
     """
     uidx = pad_multiple(uidx, 1, bb, mode="edge")
-    u = uidx.shape[0]
-    total = max(-(-u // bu), n_tiles) * bu
-    if total > u:
-        fill = jnp.zeros((total - u, uidx.shape[1]), uidx.dtype)
-        uidx = jnp.concatenate([uidx, fill], axis=0)
-    return uidx
+    return pad_multiple(uidx, 0, bu, mode="zero")
 
 
 def pad_objs_inf(objs: jnp.ndarray, bi: int) -> jnp.ndarray:

@@ -6,6 +6,10 @@ the long one -- rides the 128-wide lane dimension.  Each grid step reduces a
 (BP, B, BU) tile: min/max over the block axis, width+height per unit, max
 over the unit tile, then max-accumulates into out[p].
 
+The output is a lane-dense (BP, 128) tile per population block (the TPU
+lowering refuses a rank-1 (BP,) block): every lane holds the row's max and
+the wrapper keeps lane 0.
+
 Padding contract (enforced by ops.py): padded *units* replicate a real
 column of coordinates (bbox 0 -> neutral under max); padded *blocks*
 replicate block 0 of their unit (neutral under min/max).
@@ -21,6 +25,7 @@ from jax.experimental import pallas as pl
 from repro.kernels import _padding as P
 
 BP, BU = 8, 128
+LANES = 128       # lane-dense output tile width
 NEG = -3.4e38
 
 
@@ -35,7 +40,8 @@ def _kernel(ux, uy, o_ref):
     y = uy[...].astype(jnp.float32)
     w = jnp.max(x, axis=1) - jnp.min(x, axis=1)       # [BP, BU]
     h = jnp.max(y, axis=1) - jnp.min(y, axis=1)
-    o_ref[...] = jnp.maximum(o_ref[...], jnp.max(w + h, axis=1))
+    o_ref[...] = jnp.maximum(o_ref[...],
+                             jnp.max(w + h, axis=1, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -57,8 +63,8 @@ def maxbbox_pallas(ux: jnp.ndarray, uy: jnp.ndarray,
         _kernel,
         grid=grid,
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((BP,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct(((p + pp),), jnp.float32),
+        out_specs=pl.BlockSpec((BP, LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((p + pp, LANES), jnp.float32),
         interpret=interpret,
     )(ux, uy)
-    return out[:p]
+    return out[:p, 0]

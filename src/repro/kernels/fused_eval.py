@@ -1,40 +1,46 @@
-"""Pallas TPU kernel: fused placement evaluation for the full service batch.
+"""Pallas TPU kernels: fused placement evaluation for the full service batch.
 
-The separate `wirelength` / `bbox` kernels each re-read the decoded
-coordinates from HBM after the host has materialised per-net endpoint
-arrays ([P, N] x 4) and per-unit coordinate tensors ([P, B, U] x 2).  For
-the stacked (slots x islands x pop) batch the service evaluates every
-step, those gathers dominate the memory traffic: ~6x the coordinate bytes
-move through HBM before a single flop of Eq. 1 / Eq. 2 runs.
-
-This kernel keeps one coordinate row-block resident in VMEM and performs
-the gathers *inside* the grid step:
+The separate `wirelength` / `bbox` kernels are two launches over the same
+decoded coordinates, each with its own pass over the population.  This
+kernel reduces both objectives in ONE launch over the stacked (slots x
+islands x pop) batch:
 
     coords cx, cy : [P, G]   (population x gids, decode order)
     nets src, dst : [N] int32 gather indices into G, weights w : [N]
     units uidx    : [U, B] int32 gather table (block b of unit u -> gid)
 
-    grid (i, j) = (population tiles, max(net tiles, unit tiles))
-      step: wl[i] += sum_n ((|x[s]-x[d]| + |y[s]-y[d]|) * w)^2   (net tile j)
-            bb[i]  = max(bb[i], max_u (max-min)x + (max-min)y)   (unit tile j)
+The endpoint and unit gathers run in XLA ahead of the `pallas_call` (the
+TPU kernel compiler has no lane gather), and the gathered tiles stream in
+through `BlockSpec`s:
 
-The j axis is innermost, so both (BP,) output tiles are revisited on
-consecutive grid steps (TPU sequential-grid accumulation guarantee); step
-j == 0 initialises wl to 0 and bb to -inf.  Net and unit tile counts are
-padded up to a *shared* j extent with neutral elements (see
-`kernels._padding`): surplus net tiles carry w == 0, surplus unit rows
-gather the degenerate gid-0 unit whose bbox is exactly 0.
+    x1, y1, x2, y2 : [P, N]     endpoint coordinates of every net
+    ux, uy         : [P, B, U]  unit blocks, units on the 128-lane axis
+
+    grid (i, j) = (population tiles, max(net tiles, unit tiles))
+      step: wl[i] += sum_n ((|x1-x2| + |y1-y2|) * w)^2        (j < net tiles)
+            bb[i]  = max(bb[i], max_u (max-min)x + (max-min)y) (j < unit tiles)
+
+The j axis is innermost, so both output tiles are revisited on consecutive
+grid steps (TPU sequential-grid accumulation guarantee); step j == 0
+initialises wl to 0 and bb to -inf.  The shorter of the two walks clamps
+its block index to its last tile (the pipeline does not re-fetch an
+unchanged block) and skips the step under `pl.when`.  Outputs are
+lane-dense (BP, 128) tiles -- every lane holds the row's value and the
+wrapper keeps lane 0.  Padding follows `kernels._padding`: padded nets
+carry w == 0, padded unit rows gather the degenerate gid-0 unit whose bbox
+is exactly 0, padded blocks replicate their unit's last block.
 
 A second kernel fuses the NSGA-II domination matrix with its column
 reduction (dominated-by counts), saving the [P, P] int32 round-trip that
 `nondominated_rank` otherwise pays before its peeling loop.
 
 Like every kernel here, `ops.py` dispatches to the `ref.py` oracle off-TPU;
-interpret mode executes these bodies on CPU for the differential sweeps.
+the tests run these bodies in interpret mode on CPU.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -46,10 +52,12 @@ from repro.kernels import _padding as P
 BP = 8          # population sublane tile
 BN = 512        # nets per grid step (lane dim, 4x128)
 BU = 128        # units per grid step (lane dim)
+BB = 8          # blocks-per-unit padding multiple (sublane dim)
+LANES = 128     # lane-dense output tile width
 NEG = -3.4e38
 
 
-def _eval_kernel(cx, cy, src, dst, w, uidx, wl_ref, bb_ref):
+def _eval_kernel(n_net, n_unit, x1, y1, x2, y2, w, ux, uy, wl_ref, bb_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -57,23 +65,19 @@ def _eval_kernel(cx, cy, src, dst, w, uidx, wl_ref, bb_ref):
         wl_ref[...] = jnp.zeros_like(wl_ref)
         bb_ref[...] = jnp.full_like(bb_ref, NEG)
 
-    x = cx[...].astype(jnp.float32)                  # [BP, G]
-    y = cy[...].astype(jnp.float32)
+    @pl.when(j < n_net)
+    def _nets():                                     # Eq. 1 partial
+        dl = (jnp.abs(x1[...] - x2[...])
+              + jnp.abs(y1[...] - y2[...])) * w[...]  # padded nets: w == 0
+        wl_ref[...] += jnp.sum(dl * dl, axis=1, keepdims=True)
 
-    # Eq. 1 partial: gather this tile's net endpoints from the resident row
-    s, d = src[0], dst[0]                            # (BN,) int32
-    dl = (jnp.abs(jnp.take(x, s, axis=1) - jnp.take(x, d, axis=1))
-          + jnp.abs(jnp.take(y, s, axis=1) - jnp.take(y, d, axis=1)))
-    dl = dl * w[0].astype(jnp.float32)               # padded nets: w == 0
-    wl_ref[...] += jnp.sum(dl * dl, axis=1)
-
-    # Eq. 2 partial: gather this tile's unit blocks, bbox, max-accumulate
-    u = uidx[...]                                    # (BU, Bp) int32
-    gx = jnp.take(x, u.reshape(-1), axis=1).reshape(x.shape[0], *u.shape)
-    gy = jnp.take(y, u.reshape(-1), axis=1).reshape(y.shape[0], *u.shape)
-    wd = jnp.max(gx, axis=2) - jnp.min(gx, axis=2)   # [BP, BU]
-    ht = jnp.max(gy, axis=2) - jnp.min(gy, axis=2)
-    bb_ref[...] = jnp.maximum(bb_ref[...], jnp.max(wd + ht, axis=1))
+    @pl.when(j < n_unit)
+    def _units():                                    # Eq. 2 partial
+        x, y = ux[...], uy[...]                      # [BP, Bp, BU]
+        wd = jnp.max(x, axis=1) - jnp.min(x, axis=1)
+        ht = jnp.max(y, axis=1) - jnp.min(y, axis=1)
+        bb_ref[...] = jnp.maximum(
+            bb_ref[...], jnp.max(wd + ht, axis=1, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -88,39 +92,38 @@ def fused_eval_pallas(cx: jnp.ndarray, cy: jnp.ndarray, src: jnp.ndarray,
     """
     batch = cx.shape[:-1]
     g = cx.shape[-1]
-    cx = cx.reshape(-1, g)
-    cy = cy.reshape(-1, g)
-    p = cx.shape[0]
+    cx = P.pad_pop(cx.reshape(-1, g).astype(jnp.float32), BP)
+    cy = P.pad_pop(cy.reshape(-1, g).astype(jnp.float32), BP)
+    p = math.prod(batch)
+    pp = cx.shape[0]
 
-    # shared j extent: enough tiles for both the net and the unit walk
-    n_tiles = max(-(-src.shape[-1] // BN), -(-uidx.shape[0] // BU))
-    src, dst, w = P.pad_net_indices(src, dst, w, BN, n_tiles)
-    uidx = P.pad_unit_index(uidx, BU, bb=8, n_tiles=n_tiles)
-    cx = P.pad_pop(P.pad_multiple(cx, -1, 128), BP)
-    cy = P.pad_pop(P.pad_multiple(cy, -1, 128), BP)
-    pp, gp = cx.shape
+    src, dst, w = P.pad_net_indices(src, dst, w, BN)
+    uidx = P.pad_unit_index(uidx, BU, bb=BB).astype(jnp.int32)
+    n_net, n_unit = src.shape[-1] // BN, uidx.shape[0] // BU
+
+    # gathers in XLA; the kernel streams the gathered tiles
+    ends = [c[:, i] for c in (cx, cy) for i in (src, dst)]   # x1 x2 y1 y2
+    ux = jnp.swapaxes(cx[:, uidx], 1, 2)                     # [P, Bp, Up]
+    uy = jnp.swapaxes(cy[:, uidx], 1, 2)
     bp_u = uidx.shape[1]
 
-    grid = (pp // BP, n_tiles)
-    out_spec = pl.BlockSpec((BP,), lambda i, j: (i,))
+    net = pl.BlockSpec((BP, BN), lambda i, j: (i, jnp.minimum(j, n_net - 1)))
+    unit = pl.BlockSpec((BP, bp_u, BU),
+                        lambda i, j: (i, 0, jnp.minimum(j, n_unit - 1)))
+    out = pl.BlockSpec((BP, LANES), lambda i, j: (i, 0))
     wl, bb = pl.pallas_call(
-        _eval_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BP, gp), lambda i, j: (i, 0)),     # cx
-            pl.BlockSpec((BP, gp), lambda i, j: (i, 0)),     # cy
-            pl.BlockSpec((1, BN), lambda i, j: (0, j)),      # src
-            pl.BlockSpec((1, BN), lambda i, j: (0, j)),      # dst
-            pl.BlockSpec((1, BN), lambda i, j: (0, j)),      # w
-            pl.BlockSpec((BU, bp_u), lambda i, j: (j, 0)),   # uidx
-        ],
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((pp,), jnp.float32)] * 2,
+        functools.partial(_eval_kernel, n_net, n_unit),
+        grid=(pp // BP, max(n_net, n_unit)),
+        in_specs=[net, net, net, net,
+                  pl.BlockSpec((1, BN),
+                               lambda i, j: (0, jnp.minimum(j, n_net - 1))),
+                  unit, unit],
+        out_specs=[out, out],
+        out_shape=[jax.ShapeDtypeStruct((pp, LANES), jnp.float32)] * 2,
         interpret=interpret,
-    )(cx, cy, src.reshape(1, -1).astype(jnp.int32),
-      dst.reshape(1, -1).astype(jnp.int32),
-      w.reshape(1, -1), uidx.astype(jnp.int32))
-    return jnp.stack([wl[:p], bb[:p]], axis=-1).reshape(*batch, 2)
+    )(ends[0], ends[2], ends[1], ends[3],
+      w.reshape(1, -1).astype(jnp.float32), ux, uy)
+    return jnp.stack([wl[:p, 0], bb[:p, 0]], axis=-1).reshape(*batch, 2)
 
 
 # --------------------------------------------- fused domination + counts
@@ -141,7 +144,7 @@ def _dom_kernel(a0, a1, b0, b1, dom_ref, cnt_ref):
     lt = (ra0 < cb0) | (ra1 < cb1)
     d = le & lt
     dom_ref[...] = d.astype(jnp.int8)
-    cnt_ref[...] += jnp.sum(d.astype(jnp.int32), axis=0)
+    cnt_ref[...] += jnp.sum(d.astype(jnp.int32), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -150,8 +153,11 @@ def domination_counts_pallas(objs: jnp.ndarray, interpret: bool = False
     """objs: [P, 2] -> (dom int8 [P, P], dominated-by counts int32 [P]).
 
     Same tiling as `domination.domination_pallas`, but the row axis i is
-    the *inner* grid dim so each (BJ,) count tile is revisited on
-    consecutive steps and the column sum never leaves VMEM.
+    the *inner* grid dim so each (1, BJ) count tile is revisited on
+    consecutive steps and the column sum never leaves VMEM.  The counts
+    are a rank-2 (1, n) row: under a vmap (the service's slot axis) a
+    rank-1 block would become a (squeezed, BJ) block, which the TPU
+    lowering refuses.
     """
     p = objs.shape[0]
     o = P.pad_objs_inf(objs, BI)
@@ -170,12 +176,12 @@ def domination_counts_pallas(objs: jnp.ndarray, interpret: bool = False
         ],
         out_specs=[
             pl.BlockSpec((BI, BJ), lambda j, i: (i, j)),
-            pl.BlockSpec((BJ,), lambda j, i: (j,)),
+            pl.BlockSpec((1, BJ), lambda j, i: (0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, n), jnp.int8),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
         interpret=interpret,
     )(o0r, o1r, o0c, o1c)
-    return dom[:p, :p], cnt[:p]
+    return dom[:p, :p], cnt[0, :p]

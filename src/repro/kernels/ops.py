@@ -1,9 +1,9 @@
 """Dispatching wrappers around the Pallas kernels.
 
 Policy: on TPU backends the Pallas kernels run compiled; everywhere else the
-`ref.py` oracles run (identical semantics, XLA-fused).  Setting
-``REPRO_PALLAS=interpret`` forces the Pallas path in interpret mode -- used by
-the test suite to execute the kernel bodies on CPU.
+`ref.py` oracles run (identical semantics, XLA-fused).  The platform decides
+and nothing overrides it: tests execute the kernel bodies on CPU by calling
+the kernels with ``interpret=True`` directly.
 
 `flash_attention` carries a custom VJP whose backward pass recomputes from
 the jnp reference -- the standard memory-saving flash recompute, keeping the
@@ -12,7 +12,6 @@ fwd kernel and autodiff consistent by construction.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -32,21 +31,15 @@ from repro.kernels._padding import (  # noqa: F401  (re-exported contracts)
 )
 
 
-def _mode() -> str:
-    env = os.environ.get("REPRO_PALLAS", "auto")
-    if env == "interpret":
-        return "interpret"
-    if env == "ref":
-        return "ref"
-    return "tpu" if jax.default_backend() == "tpu" else "ref"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def wirelength2(x1, y1, x2, y2, w) -> jnp.ndarray:
     """[..., N] endpoint coords -> [...] fp32 (Eq. 1)."""
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return _ref.wirelength2_ref(x1, y1, x2, y2, w)
-    fn = functools.partial(_wl.wirelength2_pallas, interpret=(m == "interpret"))
+    fn = _wl.wirelength2_pallas
     if x1.ndim == 1:
         return fn(*(a[None] for a in (x1, y1, x2, y2, w)))[0]
     return fn(x1, y1, x2, y2, w)
@@ -54,10 +47,9 @@ def wirelength2(x1, y1, x2, y2, w) -> jnp.ndarray:
 
 def maxbbox(ux, uy) -> jnp.ndarray:
     """[..., U, B] unit-grouped coords -> [...] fp32 (Eq. 2)."""
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return _ref.maxbbox_ref(ux, uy)
-    fn = functools.partial(_bbox.maxbbox_pallas, interpret=(m == "interpret"))
+    fn = _bbox.maxbbox_pallas
     if ux.ndim == 2:
         return fn(ux[None], uy[None])[0]
     return fn(ux, uy)
@@ -65,11 +57,9 @@ def maxbbox(ux, uy) -> jnp.ndarray:
 
 def domination_matrix(objs: jnp.ndarray) -> jnp.ndarray:
     """[P, M] objectives -> bool [P, P], minimisation domination."""
-    m = _mode()
-    if m == "ref" or objs.shape[-1] != 2:
+    if not _on_tpu() or objs.shape[-1] != 2:
         return _ref.domination_ref(objs)
-    return _dom.domination_pallas(
-        objs, interpret=(m == "interpret")).astype(bool)
+    return _dom.domination_pallas(objs).astype(bool)
 
 
 def fused_eval(bx, by, src, dst, w, uidx) -> jnp.ndarray:
@@ -78,10 +68,9 @@ def fused_eval(bx, by, src, dst, w, uidx) -> jnp.ndarray:
     bx, by: [..., G] (arbitrary leading batch: slots x islands x pop);
     src/dst/w: [N] nets; uidx: [U, B] unit gather table.  Returns
     [..., 2] fp32 = (wirelength^2, max bbox).  One kernel launch for the
-    whole stacked service batch -- endpoint/unit tensors never hit HBM.
+    whole stacked service batch.
     """
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         # decode order is unit-major, so `core.objectives.unit_index` is
         # the identity table; gathering by it selects exactly the reshape
         # elements, so the reshape is bitwise the gather -- take the free
@@ -101,8 +90,7 @@ def fused_eval(bx, by, src, dst, w, uidx) -> jnp.ndarray:
                                        bx[..., dst], by[..., dst], w)
             return jnp.stack([wl2, _ref.maxbbox_ref(ux, uy)], axis=-1)
         return _ref.fused_eval_ref(bx, by, src, dst, w, uidx)
-    return _fe.fused_eval_pallas(bx, by, src, dst, w, uidx,
-                                 interpret=(m == "interpret"))
+    return _fe.fused_eval_pallas(bx, by, src, dst, w, uidx)
 
 
 def fused_domination_counts(objs: jnp.ndarray
@@ -112,12 +100,10 @@ def fused_domination_counts(objs: jnp.ndarray
     Fuses the NSGA-II domination matrix with its column reduction so the
     counts never round-trip the [P, P] matrix through HBM.
     """
-    m = _mode()
-    if m == "ref" or objs.shape[-1] != 2:
+    if not _on_tpu() or objs.shape[-1] != 2:
         dom = _ref.domination_ref(objs)
         return dom, jnp.sum(dom.astype(jnp.int32), axis=0)
-    dom, cnt = _fe.domination_counts_pallas(
-        objs, interpret=(m == "interpret"))
+    dom, cnt = _fe.domination_counts_pallas(objs)
     return dom.astype(bool), cnt
 
 
@@ -128,17 +114,15 @@ def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None,
                     logit_soft_cap: Optional[float] = None):
     """q: [B,H,S,D]; k,v: [B,Hkv,T,D] -> [B,H,S,D]."""
-    m = _mode()
     if logit_soft_cap is not None:
         # soft-cap variant only exists on the ref path (none of the assigned
         # archs enable it at the kernel level)
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                         logit_soft_cap=logit_soft_cap)
-    if m == "ref":
+    if not _on_tpu():
         # memory-bounded XLA path: never materialises [S, T]
         return _xf.flash_attention_xla(q, k, v, causal=causal, window=window)
-    return _fa.flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      interpret=(m == "interpret"))
+    return _fa.flash_attention_pallas(q, k, v, causal=causal, window=window)
 
 
 def _fa_fwd(q, k, v, causal, window, logit_soft_cap):

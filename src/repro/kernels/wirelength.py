@@ -10,7 +10,10 @@ walks (population tiles, net tiles); the net axis is innermost so each output
 tile is revisited and accumulated in place (TPU sequential-grid guarantee).
 
 Tiling: BP x BN = 8 x 512 fp32 tiles -> 5 inputs * 16 KiB = 80 KiB VMEM per
-step, MXU-free pure-VPU workload, lane dim 512 = 4x128 registers.
+step, MXU-free pure-VPU workload, lane dim 512 = 4x128 registers.  The
+output is a lane-dense (BP, 128) tile per population block (the TPU lowering
+refuses a rank-1 (BP,) block): every lane holds the row's sum and the
+wrapper keeps lane 0.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ from jax.experimental import pallas as pl
 from repro.kernels import _padding as P
 
 BP, BN = 8, 512
+LANES = 128       # lane-dense output tile width
 
 
 def _kernel(x1, y1, x2, y2, w, o_ref):
@@ -34,7 +38,7 @@ def _kernel(x1, y1, x2, y2, w, o_ref):
 
     dl = (jnp.abs(x1[...] - x2[...]) + jnp.abs(y1[...] - y2[...])) * w[...]
     dl = dl.astype(jnp.float32)
-    o_ref[...] += jnp.sum(dl * dl, axis=1)
+    o_ref[...] += jnp.sum(dl * dl, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -52,8 +56,8 @@ def wirelength2_pallas(x1: jnp.ndarray, y1: jnp.ndarray, x2: jnp.ndarray,
         _kernel,
         grid=grid,
         in_specs=[spec] * 5,
-        out_specs=pl.BlockSpec((BP,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct(((p + pp),), jnp.float32),
+        out_specs=pl.BlockSpec((BP, LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((p + pp, LANES), jnp.float32),
         interpret=interpret,
     )(x1, y1, x2, y2, w)
-    return out[:p]
+    return out[:p, 0]

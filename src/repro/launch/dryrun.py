@@ -246,8 +246,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):      # jax <= 0.4.x: list per device
-            ca = ca[0] if ca else {}
         text = compiled.as_text()
         walk = hloparse.analyze(text)      # trip-count-aware per-device walk
         chips = 512 if multi_pod else 256

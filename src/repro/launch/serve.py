@@ -30,11 +30,11 @@ identical jobs is answered from cache / warm-started), `--policy
 sub-populations with ring champion migration (`core.islands`) -- per-job
 quality scales with N at the same wallclock step count.
 
-Compile-latency flags (`runtime.compile_cache` / `serve.prewarm`):
-`--compile-cache-dir D` (or the `REPRO_COMPILE_CACHE_DIR` environment
-variable) turns on jax's persistent compilation cache rooted at D, so a
-restarted launcher deserializes its pool programs instead of recompiling;
-`--prewarm` attaches the background AOT compiler to the scheduler --
+Compile latency (`runtime.compile_cache` / `serve.prewarm`): placement
+mode always turns on jax's persistent compilation cache, at
+`$JAX_COMPILATION_CACHE_DIR` when it is set and at `<checkout>/.jax_cache`
+otherwise, so a restarted launcher deserializes its pool programs instead
+of recompiling; `--prewarm` attaches the background AOT compiler to the scheduler --
 store-predicted pools (`--cache-path` traffic) build off-thread before
 their first job, and autoscale ladder sizes pre-compile before `grow()`.
 
@@ -412,12 +412,6 @@ def main():
                     help="pool stepping policy (serve.policy)")
     ap.add_argument("--autoscale", action="store_true",
                     help="grow pools along the slot ladder on queue depth")
-    ap.add_argument("--compile-cache-dir", default=None, metavar="DIR",
-                    help="enable jax's persistent compilation cache rooted "
-                         "here (also honoured via the "
-                         "REPRO_COMPILE_CACHE_DIR environment variable): a "
-                         "restarted process deserializes its pool programs "
-                         "instead of recompiling")
     ap.add_argument("--prewarm", action="store_true",
                     help="background AOT pool compiler (serve.prewarm): "
                          "store-predicted pools and autoscale ladder sizes "
@@ -456,10 +450,8 @@ def main():
 
     if args.placement:
         from repro.runtime import compile_cache
-        enabled = compile_cache.maybe_enable_from_env(args.compile_cache_dir)
-        if enabled:
-            print(f"persistent compilation cache: {enabled} "
-                  f"({compile_cache.cache_salt()})")
+        print(f"persistent compilation cache: {compile_cache.enable()} "
+              f"({compile_cache.cache_salt()})")
         finalize = _telemetry_setup(args)
         try:
             if args.frontend:

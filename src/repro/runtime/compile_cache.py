@@ -6,8 +6,8 @@ backend compiles before its first generation, and every geometric-ladder
 `grow()` or lazily-created scheduler pool repeats the bill.  This module is
 the runtime half of the fix (the serving half is `serve.prewarm`):
 
-  * **persistent cache** -- `enable(cache_dir)` turns on jax's persistent
-    compilation cache rooted at `cache_dir` with thresholds zeroed, so
+  * **persistent cache** -- `enable()` turns on jax's persistent
+    compilation cache with thresholds zeroed, so
     EVERY program the service compiles (step, init, warm-init, fill, at
     every slot-ladder size) is serialized to disk.  A restarted process --
     or a CI runner restoring the directory -- deserializes instead of
@@ -25,6 +25,16 @@ the runtime half of the fix (the serving half is `serve.prewarm`):
     `PlacementService` separates *blocking* compiles (in the stepping
     loop's thread) from background prewarm compiles.
 
+Where the cache lives is placed from outside: `$JAX_COMPILATION_CACHE_DIR`
+when it is set (jax reads that variable itself, and nothing here sets
+another directory), else one fixed path inside the checkout,
+`<checkout>/.jax_cache` (gitignored).  A fixed path matters because the
+directory is part of what a later run must find again.  Entry points
+(`launch/serve.py`, `chip_smoke.py`, the benchmarks) call `enable()`;
+importing the library never turns the cache on.  This module imports jax
+only inside the functions that need it, so a parent process that only
+launches probes can resolve `cache_dir()` without taking a device.
+
 Nothing here is load-bearing for results: the cache and the meter change
 when compilation happens, never what the compiled programs produce, and
 with neither enabled the service is bitwise the pre-PR code path.
@@ -36,11 +46,10 @@ import hashlib
 import os
 import threading
 import warnings
+from pathlib import Path
 from typing import Any, Dict, Optional
 
-import jax
-
-# jax.monitoring event keys (jax 0.4.x).  A jax upgrade that renames them
+# jax.monitoring event keys.  A jax upgrade that renames them
 # degrades the meter to "nothing observed" -- callers treat 0-compiles-seen
 # with `events_seen == 0` as "meter unavailable", never as "no compiles".
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -207,29 +216,37 @@ def meter() -> CompileMeter:
 
 _ENABLED_DIR: Optional[str] = None
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def enable(cache_dir: str) -> str:
-    """Enable jax's persistent compilation cache rooted at `cache_dir`.
+
+def cache_dir() -> str:
+    """The persistent-cache directory entry points use:
+    `$JAX_COMPILATION_CACHE_DIR` when set, else `<checkout>/.jax_cache`."""
+    return os.environ.get(ENV_VAR) or str(DEFAULT_DIR)
+
+
+def enable() -> str:
+    """Enable jax's persistent compilation cache at `cache_dir()`.
 
     Thresholds are zeroed (`min_entry_size`/`min_compile_time`) so every
     service program persists -- the pool-shaped programs are individually
     small but collectively the whole cold-start bill.  Safe to call more
-    than once; the last directory wins.  Returns the directory.
+    than once.  Returns the directory.
     """
+    import jax
+
     global _ENABLED_DIR
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except (AttributeError, ValueError) as e:        # pragma: no cover
-        warnings.warn(f"persistent compilation cache unavailable on this "
-                      f"jax ({e}); continuing without it", stacklevel=2)
-        return cache_dir
+    directory = cache_dir()
+    os.makedirs(directory, exist_ok=True)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _reset_jax_cache_latch()
-    _ENABLED_DIR = cache_dir
+    _ENABLED_DIR = directory
     meter().install()
-    return cache_dir
+    return directory
 
 
 def _reset_jax_cache_latch() -> None:
@@ -237,24 +254,18 @@ def _reset_jax_cache_latch() -> None:
     of the process; a process that compiled anything before `enable()`
     (imports with eager ops, a test suite, a service enabling mid-flight)
     would silently never persist.  `reset_cache()` un-latches it so the
-    new directory takes effect; private API, so a jax that moved it just
-    degrades to the latch's old behaviour (enable-before-first-compile
-    still works)."""
-    try:
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:                                # pragma: no cover
-        pass
+    new directory takes effect."""
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 def disable() -> None:
     """Turn the persistent cache back off (tests; the listener-based meter
     stays installed -- listeners are process-permanent)."""
+    import jax
+
     global _ENABLED_DIR
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except (AttributeError, ValueError):             # pragma: no cover
-        pass
+    jax.config.update("jax_compilation_cache_dir", None)
     _reset_jax_cache_latch()
     _ENABLED_DIR = None
 
@@ -262,15 +273,6 @@ def disable() -> None:
 def enabled_dir() -> Optional[str]:
     """The active persistent-cache directory, or None when disabled."""
     return _ENABLED_DIR
-
-
-def maybe_enable_from_env(flag_dir: Optional[str] = None) -> Optional[str]:
-    """`enable()` from an explicit flag value or the
-    `REPRO_COMPILE_CACHE_DIR` environment variable; no-op when neither is
-    set (entry points call this so `--compile-cache-dir` and the env var
-    behave identically)."""
-    cache_dir = flag_dir or os.environ.get("REPRO_COMPILE_CACHE_DIR")
-    return enable(cache_dir) if cache_dir else None
 
 
 # ------------------------------------------------------------------- salt
@@ -282,6 +284,7 @@ def cache_salt() -> str:
     string exists for the layers *around* the cache -- CI `actions/cache`
     keys and prewarm bookkeeping -- so they partition storage the same way
     the entries inside it are partitioned."""
+    import jax
     return (f"jax{jax.__version__}-{jax.default_backend()}"
             f"-d{jax.device_count()}")
 

@@ -1,22 +1,10 @@
-"""Version-spanning shims for jax APIs that moved between releases.
+"""One home for the mesh, shard_map and axis-name collective calls.
 
-The container pins jax 0.4.x while parts of this codebase were written
-against the current API.  The call sites that drifted:
-
-  * `shard_map`: top-level `jax.shard_map(..., check_vma=)` now,
-    `jax.experimental.shard_map.shard_map(..., check_rep=)` on 0.4.x.
-  * `jax.make_mesh`: grew an `axis_types=` kwarg (`jax.sharding.AxisType`)
-    after 0.4.x; plain construction is equivalent for our Auto meshes.
-  * axis-name collectives: `jax.lax.axis_size` only exists on newer jax
-    (0.4.x spells it `psum(1, axis)`), and the blessed import path for the
-    others has moved before.  `axis_index` / `axis_size` / `psum` /
-    `ppermute` / `all_gather` below are the uniform axis-name API every
-    shard_mapped caller (core.islands ring migration, evolve.run_islands,
-    launch.mesh) consumes, plus `ring_perm` for the canonical
-    champion-ring permutation.
-
-Route every mesh/shard_map/collective use through here so a jax upgrade
-is a one-file change.
+Every shard_mapped caller (core.islands ring migration, evolve.run_islands,
+launch.mesh) goes through `shard_map`, `make_mesh` and the uniform
+axis-name API below (`axis_index` / `axis_size` / `psum` / `ppermute` /
+`all_gather`, plus `ring_perm` for the canonical champion-ring
+permutation), so a jax API change is a one-file change.
 """
 from __future__ import annotations
 
@@ -24,52 +12,31 @@ from typing import List, Tuple
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, check_vma=False,
-                             in_specs=in_specs, out_specs=out_specs)
-else:                                                  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_04
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_04(f, mesh=mesh, check_rep=False,
-                             in_specs=in_specs, out_specs=out_specs)
-
-
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, check_vma=False,
+                         in_specs=in_specs, out_specs=out_specs)
 
 
 def make_mesh(shape, names) -> jax.sharding.Mesh:
-    """`jax.make_mesh` with Auto axis types where the API supports them.
-
-    Resolved once at import (like the shard_map shim above) so a caller's
-    own TypeError is never masked by a version-probe retry.
-    """
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            shape, names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(names))
-    return jax.make_mesh(shape, names)                 # jax <= 0.4.x
+    """`jax.make_mesh` with Auto axis types."""
+    return jax.make_mesh(
+        shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 # ------------------------------------------------ axis-name collectives
 #
-# Thin, version-stable wrappers: callers never import jax.lax collectives
-# directly, so a future rename (like shard_map's) stays a one-file change.
+# Thin wrappers: callers never import jax.lax collectives directly, so a
+# future rename stays a one-file change.
 
 def axis_index(axis: str) -> jax.Array:
     """This shard's index along a shard_map/pmap axis name."""
     return jax.lax.axis_index(axis)
 
 
-if hasattr(jax.lax, "axis_size"):
-    def axis_size(axis: str) -> int:
-        """Number of shards along an axis name."""
-        return jax.lax.axis_size(axis)
-else:                                                  # jax <= 0.4.x
-    def axis_size(axis: str) -> int:
-        """Number of shards along an axis name (0.4.x spelling)."""
-        return jax.lax.psum(1, axis_name=axis)
+def axis_size(axis: str) -> int:
+    """Number of shards along an axis name."""
+    return jax.lax.axis_size(axis)
 
 
 def psum(x, axis: str):

@@ -30,9 +30,8 @@ fixed per pool at construction: they are baked into the compiled step.
 Jobs whose config disagrees on those belong in a different pool --
 `serve.scheduler.PlacementScheduler` routes mixed traffic across pools.
 `fused=True` configs evaluate the pool's whole stacked (slots x islands x
-pop) batch through the fused Pallas pipeline (`kernels.fused_eval`): the
-slot/island vmaps stack batch axes onto ONE kernel launch instead of
-materialising per-net endpoint and per-unit coordinate tensors per slot.
+pop) batch through the fused Pallas pipeline (`kernels.fused_eval`): one
+kernel launch reduces both objectives over the stacked batch.
 
 Warm starts: `submit(init_state=...)` seeds a job from a genotype (e.g.
 `core.transfer.migrate`'s projection of a sibling-device champion) via a
@@ -637,6 +636,14 @@ class PlacementService:
             return self._step_fn._cache_size()
         except AttributeError:
             return -1
+
+    def lowered_step(self):
+        """The batched step lowered at the pool's current shapes, for
+        reading the program the pool runs (`.compile().as_text()`,
+        `.compile().memory_analysis()`)."""
+        return self._step_fn.lower(
+            self._traced_dev(), self.states,
+            jnp.array(self.slot_seed), jnp.array(self.slot_gens))
 
     def run_jobs(self, specs: List[Dict]) -> List[PlacementJob]:
         """Rolling admission: submit specs as slots free up, step until

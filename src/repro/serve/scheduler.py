@@ -257,6 +257,10 @@ class PlacementScheduler:
                 self.prewarmer.prewarm_grow(svc, 2 * svc.n_slots)
         return self._pools[key]
 
+    def pools(self) -> Dict[str, PlacementService]:
+        """The live pools by label (the `stats()["pools"]` keys)."""
+        return {self._label(k): svc for k, svc in self._pools.items()}
+
     # ------------------------------------------------------------ prewarm
 
     def prewarm(self, device: str, cfg, algo: str = "nsga2",

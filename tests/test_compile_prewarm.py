@@ -23,6 +23,7 @@ Covers the PR's acceptance contracts:
 """
 import json
 import threading
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +72,7 @@ def test_meter_counts_and_thread_scopes():
     assert m.recompiles <= m.compiles
 
 
-def test_persistent_cache_round_trip(tmp_path):
+def test_persistent_cache_round_trip(tmp_path, monkeypatch):
     """enable() -> compile -> clear in-memory caches -> reload from disk.
 
     `jax.clear_caches()` drops the in-process executable caches, so the
@@ -80,8 +81,9 @@ def test_persistent_cache_round_trip(tmp_path):
     compile budget."""
     m = compile_cache.meter().install()
     d = str(tmp_path / "xc")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
     try:
-        assert compile_cache.enable(d) == d
+        assert compile_cache.enable() == d
         assert compile_cache.enabled_dir() == d
 
         c = float(np.random.default_rng(1).standard_normal())
@@ -104,16 +106,24 @@ def test_persistent_cache_round_trip(tmp_path):
         assert compile_cache.enabled_dir() is None
 
 
+def test_cache_dir_defaults_to_checkout(monkeypatch):
+    """With $JAX_COMPILATION_CACHE_DIR unset the cache has one fixed home
+    inside the checkout, so a later run finds it again."""
+    checkout = Path(__file__).resolve().parents[1]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.cache_dir() == str(checkout / ".jax_cache")
+
+
 def test_maybe_enable_from_env(tmp_path, monkeypatch):
+    """Entry points place the cache from outside: with
+    $JAX_COMPILATION_CACHE_DIR set, `enable()` roots the cache there."""
+    d = str(tmp_path / "envxc")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    assert compile_cache.cache_dir() == d
     try:
-        monkeypatch.delenv("REPRO_COMPILE_CACHE_DIR", raising=False)
-        assert compile_cache.maybe_enable_from_env(None) is None
-        d = str(tmp_path / "envxc")
-        monkeypatch.setenv("REPRO_COMPILE_CACHE_DIR", d)
-        assert compile_cache.maybe_enable_from_env(None) == d
-        # an explicit flag beats the environment
-        d2 = str(tmp_path / "flagxc")
-        assert compile_cache.maybe_enable_from_env(d2) == d2
+        assert compile_cache.enable() == d
+        assert compile_cache.enabled_dir() == d
+        assert jax.config.jax_compilation_cache_dir == d
     finally:
         compile_cache.disable()
 

@@ -89,8 +89,8 @@ def test_pad_net_indices_weights_are_zero():
     src = jnp.arange(11, dtype=jnp.int32)
     dst = jnp.arange(11, dtype=jnp.int32)[::-1]
     w = jnp.ones(11)
-    ps, pd, pw = ops.pad_net_indices(src, dst, w, 512, n_tiles=3)
-    assert ps.shape == (1536,)
+    ps, pd, pw = ops.pad_net_indices(src, dst, w, 512)
+    assert ps.shape == (512,)
     np.testing.assert_array_equal(np.asarray(pw[11:]), 0.0)
     # indices stay in range of any gid table (they pad with 0)
     assert int(jnp.max(ps)) <= 10 and int(jnp.min(ps)) >= 0
@@ -98,8 +98,8 @@ def test_pad_net_indices_weights_are_zero():
 
 def test_pad_unit_index_rows_are_gid_zero():
     uidx = jnp.arange(5 * 7, dtype=jnp.int32).reshape(5, 7) + 3
-    p = ops.pad_unit_index(uidx, 128, bb=8, n_tiles=2)
-    assert p.shape == (256, 8)
+    p = ops.pad_unit_index(uidx, 128, bb=8)
+    assert p.shape == (128, 8)
     # padded blocks replicate each unit's last block (edge padding)
     np.testing.assert_array_equal(np.asarray(p[:5, 7]),
                                   np.asarray(uidx[:, -1]))
@@ -171,7 +171,7 @@ def test_pad_multiple_modes():
 # ------------------------------------------------------ property tests
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000),
        p=st.integers(min_value=2, max_value=40))
 def test_domination_rank_permutation_invariant(seed, p):
@@ -184,7 +184,7 @@ def test_domination_rank_permutation_invariant(seed, p):
     np.testing.assert_array_equal(r[np.asarray(perm)], rp)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000),
        shift=st.integers(min_value=-500, max_value=500))
 def test_bbox_translation_invariant(seed, shift):
@@ -198,7 +198,7 @@ def test_bbox_translation_invariant(seed, shift):
                                atol=1e-3)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000),
        scale=st.integers(min_value=1, max_value=8))
 def test_wirelength_quadratic_in_weights(seed, scale):
@@ -218,10 +218,9 @@ def test_wirelength_quadratic_in_weights(seed, scale):
 # --------------------------------------------------- dispatch equivalence
 
 
-def test_ops_fused_eval_bitwise_matches_unfused_dispatch(monkeypatch):
+def test_ops_fused_eval_bitwise_matches_unfused_dispatch():
     """On the CPU ref path, the fused dispatch is composed from the same
     oracles as the two-op dispatch -- bitwise identical."""
-    monkeypatch.delenv("REPRO_PALLAS", raising=False)
     c = make_eval_case(6, 96, 200, 37, 11)
     fused = ops.fused_eval(*c)
     wl = ops.wirelength2(c.cx[:, c.src], c.cy[:, c.src],
@@ -231,8 +230,7 @@ def test_ops_fused_eval_bitwise_matches_unfused_dispatch(monkeypatch):
     np.testing.assert_array_equal(np.asarray(fused[..., 1]), np.asarray(bb))
 
 
-def test_ops_fused_domination_counts_matches_matrix(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+def test_ops_fused_domination_counts_matches_matrix():
     objs = make_dom_case(50, seed=3)
     dom, cnt = ops.fused_domination_counts(objs)
     want = ops.domination_matrix(objs)
