@@ -1,0 +1,11 @@
+"""rank_device_ms.backlog: device milliseconds per execution of the pool's step
+program (`jit__step`) in the `rank` phase of NSGA-II -- non-dominated
+ranking and crowding distance of the parents and of the combined population,
+and the truncation order -- from the profiler trace, each operation charged
+to the phase its `jax.named_scope` names (`bench/scope_reduce.py`)."""
+from bench import scope_reduce
+
+
+def read(run):
+    ms = scope_reduce.phase_ms(run)
+    return None if ms is None else ms["rank"]

@@ -230,14 +230,18 @@ def init_state(problem: Problem, key: jax.Array, cfg: NSGA2Config
 def _eval_reduced(problem: Problem, perms, fused: bool = False
                   ) -> jnp.ndarray:
     if fused:
-        bx, by = jax.vmap(
-            lambda ps: G.decode_reduced(problem, ps))(perms)
-        s, d = jnp.asarray(problem.net_src), jnp.asarray(problem.net_dst)
-        w = jnp.asarray(problem.net_w)
-        return ops.fused_eval(bx, by, s, d, w, O.unit_index(problem))
+        with jax.named_scope("decode"):
+            bx, by = jax.vmap(
+                lambda ps: G.decode_reduced(problem, ps))(perms)
+        with jax.named_scope("evaluate"):
+            s = jnp.asarray(problem.net_src)
+            d = jnp.asarray(problem.net_dst)
+            w = jnp.asarray(problem.net_w)
+            return ops.fused_eval(bx, by, s, d, w, O.unit_index(problem))
 
     def one(ps):
-        bx, by = G.decode_reduced(problem, ps)
+        with jax.named_scope("decode"):
+            bx, by = G.decode_reduced(problem, ps)
         wl2, bb = O.objectives_from_coords(problem, bx, by)
         return jnp.stack([wl2, bb])
 
@@ -249,32 +253,46 @@ def step_impl(problem: Problem, cfg: NSGA2Config, state, key):
 
     Unjitted body: float config fields may be JAX tracers (portfolio
     batching); only `pop_size`, `perm_swaps`, `reduced` must be concrete.
+
+    Each phase runs under a `jax.named_scope` -- `rank`, `select`, `vary`,
+    and `decode` / `evaluate` inside the evaluation -- so every operation's
+    `op_name` says which phase it belongs to; a profile charges device
+    time to the phases by it.  Scopes are metadata: no operation changes.
     """
     pop, objs = state["pop"], state["objs"]
     p = cfg.pop_size
-    rank = nondominated_rank(objs, cfg.fused)
-    crowd = crowding_distance(objs, rank)
-    k1, k2, k3 = jax.random.split(key, 3)
-    pa = _tournament(k1, rank, crowd, p)
-    pb = _tournament(k2, rank, crowd, p)
+    with jax.named_scope("rank"):
+        rank = nondominated_rank(objs, cfg.fused)
+        crowd = crowding_distance(objs, rank)
+    with jax.named_scope("select"):
+        k1, k2, k3 = jax.random.split(key, 3)
+        pa = _tournament(k1, rank, crowd, p)
+        pb = _tournament(k2, rank, crowd, p)
 
-    def take(idx):
-        return jax.tree.map(lambda a: a[idx], pop)
+        def take(idx):
+            return jax.tree.map(lambda a: a[idx], pop)
 
+        parents_a, parents_b = take(pa), take(pb)
     vary = _vary_one_reduced if cfg.reduced else _vary_one
-    children = jax.vmap(lambda k, g1, g2: vary(k, g1, g2, cfg))(
-        jax.random.split(k3, p), take(pa), take(pb))
+    with jax.named_scope("vary"):
+        children = jax.vmap(lambda k, g1, g2: vary(k, g1, g2, cfg))(
+            jax.random.split(k3, p), parents_a, parents_b)
+    # decode and evaluate scope themselves inside the evaluation
     cobjs = (_eval_reduced(problem, children, cfg.fused) if cfg.reduced
              else O.evaluate_population(problem, children, cfg.fused))
 
     # (mu + lambda) environmental selection on the combined population
-    allpop = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), pop, children)
-    allobjs = jnp.concatenate([objs, cobjs])
-    arank = nondominated_rank(allobjs, cfg.fused)
-    acrowd = crowding_distance(allobjs, arank)
-    order = _lexsort_rank_crowd(arank, acrowd)[:p]
-    return {"pop": jax.tree.map(lambda a: a[order], allpop),
-            "objs": allobjs[order]}
+    with jax.named_scope("select"):
+        allpop = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                              pop, children)
+        allobjs = jnp.concatenate([objs, cobjs])
+    with jax.named_scope("rank"):
+        arank = nondominated_rank(allobjs, cfg.fused)
+        acrowd = crowding_distance(allobjs, arank)
+        order = _lexsort_rank_crowd(arank, acrowd)[:p]
+    with jax.named_scope("select"):
+        return {"pop": jax.tree.map(lambda a: a[order], allpop),
+                "objs": allobjs[order]}
 
 
 step = functools.partial(jax.jit, static_argnums=(0, 1))(step_impl)

@@ -3,7 +3,9 @@
 `evaluate` maps one genotype to the two objectives; `evaluate_population`
 vmaps the whole population through decode + objectives in a single jitted
 program (the paper's per-candidate Java evaluation becomes one fused batch).
-Hot reductions route through `repro.kernels.ops` (Pallas on TPU).
+Hot reductions route through `repro.kernels.ops` (Pallas on TPU).  The
+decode runs under `jax.named_scope("decode")` and Eqs. 1-2 under
+`jax.named_scope("evaluate")`, so a profile can charge them apart.
 """
 from __future__ import annotations
 
@@ -41,23 +43,25 @@ def objectives_from_coords(problem: Problem, bx: jnp.ndarray, by: jnp.ndarray,
     `fused=True` routes through `ops.fused_eval` -- one kernel launch
     reduces both objectives.
     """
-    s, d = jnp.asarray(problem.net_src), jnp.asarray(problem.net_dst)
-    w = jnp.asarray(problem.net_w)
-    if fused:
-        res = ops.fused_eval(bx, by, s, d, w, unit_index(problem))
-        return res[..., 0], res[..., 1]
-    wl2 = ops.wirelength2(bx[s], by[s], bx[d], by[d], w)
-    ux = bx.reshape(problem.n_units, BLOCKS_PER_UNIT)
-    uy = by.reshape(problem.n_units, BLOCKS_PER_UNIT)
-    bb = ops.maxbbox(ux, uy)
-    return wl2, bb
+    with jax.named_scope("evaluate"):
+        s, d = jnp.asarray(problem.net_src), jnp.asarray(problem.net_dst)
+        w = jnp.asarray(problem.net_w)
+        if fused:
+            res = ops.fused_eval(bx, by, s, d, w, unit_index(problem))
+            return res[..., 0], res[..., 1]
+        wl2 = ops.wirelength2(bx[s], by[s], bx[d], by[d], w)
+        ux = bx.reshape(problem.n_units, BLOCKS_PER_UNIT)
+        uy = by.reshape(problem.n_units, BLOCKS_PER_UNIT)
+        bb = ops.maxbbox(ux, uy)
+        return wl2, bb
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2))
 def evaluate(problem: Problem, g: G.Genotype, fused: bool = False
              ) -> jnp.ndarray:
     """Genotype -> objectives [2] = (wl^2, max bbox)."""
-    bx, by = G.decode(problem, g)
+    with jax.named_scope("decode"):
+        bx, by = G.decode(problem, g)
     wl2, bb = objectives_from_coords(problem, bx, by, fused)
     return jnp.stack([wl2, bb])
 
@@ -72,10 +76,13 @@ def evaluate_population(problem: Problem, pop: G.Genotype,
     (slots, islands) stack further batch axes onto the same launch.
     """
     if fused:
-        bx, by = jax.vmap(lambda g: G.decode(problem, g))(pop)
-        s, d = jnp.asarray(problem.net_src), jnp.asarray(problem.net_dst)
-        w = jnp.asarray(problem.net_w)
-        return ops.fused_eval(bx, by, s, d, w, unit_index(problem))
+        with jax.named_scope("decode"):
+            bx, by = jax.vmap(lambda g: G.decode(problem, g))(pop)
+        with jax.named_scope("evaluate"):
+            s = jnp.asarray(problem.net_src)
+            d = jnp.asarray(problem.net_dst)
+            w = jnp.asarray(problem.net_w)
+            return ops.fused_eval(bx, by, s, d, w, unit_index(problem))
     return jax.vmap(lambda g: evaluate(problem, g))(pop)
 
 
@@ -84,11 +91,14 @@ def evaluate_flat_population(problem: Problem, z: jnp.ndarray,
                              fused: bool = False) -> jnp.ndarray:
     """Continuous-encoded population [P, D] -> [P, 2] (CMA-ES / SA path)."""
     if fused:
-        bx, by = jax.vmap(
-            lambda zz: G.decode(problem, G.from_flat(problem, zz)))(z)
-        s, d = jnp.asarray(problem.net_src), jnp.asarray(problem.net_dst)
-        w = jnp.asarray(problem.net_w)
-        return ops.fused_eval(bx, by, s, d, w, unit_index(problem))
+        with jax.named_scope("decode"):
+            bx, by = jax.vmap(
+                lambda zz: G.decode(problem, G.from_flat(problem, zz)))(z)
+        with jax.named_scope("evaluate"):
+            s = jnp.asarray(problem.net_src)
+            d = jnp.asarray(problem.net_dst)
+            w = jnp.asarray(problem.net_w)
+            return ops.fused_eval(bx, by, s, d, w, unit_index(problem))
     return jax.vmap(lambda zz: evaluate(problem, G.from_flat(problem, zz)))(z)
 
 

@@ -61,6 +61,7 @@ def maxbbox_pallas(ux: jnp.ndarray, uy: jnp.ndarray,
     spec = pl.BlockSpec((BP, b + bb, BU), lambda i, j: (i, 0, j))
     out = pl.pallas_call(
         _kernel,
+        name="maxbbox_pallas",
         grid=grid,
         in_specs=[spec, spec],
         out_specs=pl.BlockSpec((BP, LANES), lambda i, j: (i, 0)),

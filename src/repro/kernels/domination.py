@@ -43,6 +43,7 @@ def domination_pallas(objs: jnp.ndarray, interpret: bool = False
     grid = (n // BI, n // BJ)
     out = pl.pallas_call(
         _kernel,
+        name="domination_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((BI, 1), lambda i, j: (i, 0)),

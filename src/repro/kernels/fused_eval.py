@@ -113,6 +113,7 @@ def fused_eval_pallas(cx: jnp.ndarray, cy: jnp.ndarray, src: jnp.ndarray,
     out = pl.BlockSpec((BP, LANES), lambda i, j: (i, 0))
     wl, bb = pl.pallas_call(
         functools.partial(_eval_kernel, n_net, n_unit),
+        name="fused_eval_pallas",
         grid=(pp // BP, max(n_net, n_unit)),
         in_specs=[net, net, net, net,
                   pl.BlockSpec((1, BN),
@@ -167,6 +168,7 @@ def domination_counts_pallas(objs: jnp.ndarray, interpret: bool = False
     grid = (n // BJ, n // BI)            # (j cols outer, i rows inner)
     dom, cnt = pl.pallas_call(
         _dom_kernel,
+        name="domination_counts_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((BI, 1), lambda j, i: (i, 0)),

@@ -54,6 +54,7 @@ def wirelength2_pallas(x1: jnp.ndarray, y1: jnp.ndarray, x2: jnp.ndarray,
     spec = pl.BlockSpec((BP, BN), lambda i, j: (i, j))
     out = pl.pallas_call(
         _kernel,
+        name="wirelength2_pallas",
         grid=grid,
         in_specs=[spec] * 5,
         out_specs=pl.BlockSpec((BP, LANES), lambda i, j: (i, 0)),

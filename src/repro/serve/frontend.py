@@ -374,7 +374,14 @@ class PlacementFrontend:
         # else: finished in the same breath; resolves via _do_step
 
     def _do_step(self) -> None:
-        for job in self.scheduler.step():
+        finished = self.scheduler.step()
+        # the stepping thread's own work between pool steps, as a leaf
+        # span (`serve.tracing`): resolving handles, pushing progress
+        with tracing.tracer().span("frontend.publish"):
+            self._publish(finished)
+
+    def _publish(self, finished) -> None:
+        for job in finished:
             handle = self._by_jid.get(job.jid)
             if handle is None:
                 continue                   # not ours (direct submitter)

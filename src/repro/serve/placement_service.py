@@ -84,7 +84,7 @@ _M_HARVESTED = _REG.counter(
 _M_CANCELLED = _REG.counter(
     "repro_jobs_cancelled_total", "In-flight slots freed early by cancel()")
 _M_STEP_MS = _REG.histogram(
-    "repro_service_step_ms", "Wall ms per batched service step",
+    "repro_service_step_ms", "Wall ms per batched service step, per pool",
     buckets=telemetry.DEFAULT_LATENCY_BUCKETS_MS)
 _M_BEST = _REG.gauge(
     "repro_pool_best_metric",
@@ -246,11 +246,6 @@ class PlacementService:
 
         # fill the pool with throwaway states so step() shapes exist from
         # the first call (vacant slots evolve garbage; it is never read)
-        # per-pool step-latency histogram (the registry-global one
-        # aggregates across pools; this instance feeds stats())
-        self._step_hist = telemetry.Histogram(
-            "step_ms", buckets=telemetry.DEFAULT_LATENCY_BUCKETS_MS)
-
         k_fill = jax.random.fold_in(self.key, 0x5eed)
         with self._blocking():
             self.states = self._fill_fn(self._traced_dev(),
@@ -361,20 +356,24 @@ class PlacementService:
             tracing.tracer().instant("job.admitted", trace_id,
                                      slot=slot, pool=self.label,
                                      warm=job.warm)
-        traced_dev = {k: jnp.float32(v) for k, v in traced.items()}
-        with self._blocking():
-            if init_state is None:
-                state1 = self._init_fn(traced_dev, jax.random.PRNGKey(seed))
-            else:
-                pop, fresh = warmstart.canonicalize(
-                    self.problem, init_state, self._seed_rows)
-                state1 = self._warm_init_fn(
-                    traced_dev, jax.tree.map(jnp.asarray, pop),
-                    jnp.asarray(fresh), jnp.float32(jitter),
-                    jnp.float32(sigma_shrink), jax.random.PRNGKey(seed))
-        # splice the single job state into the pool at `slot`
-        self.states = jax.tree.map(
-            lambda pool, one: pool.at[slot].set(one), self.states, state1)
+        with tracing.tracer().span("job.init", trace_id, pool=self.label,
+                                   slot=slot):
+            traced_dev = {k: jnp.float32(v) for k, v in traced.items()}
+            with self._blocking():
+                if init_state is None:
+                    state1 = self._init_fn(traced_dev,
+                                           jax.random.PRNGKey(seed))
+                else:
+                    pop, fresh = warmstart.canonicalize(
+                        self.problem, init_state, self._seed_rows)
+                    state1 = self._warm_init_fn(
+                        traced_dev, jax.tree.map(jnp.asarray, pop),
+                        jnp.asarray(fresh), jnp.float32(jitter),
+                        jnp.float32(sigma_shrink), jax.random.PRNGKey(seed))
+            # splice the single job state into the pool at `slot`
+            self.states = jax.tree.map(
+                lambda pool, one: pool.at[slot].set(one), self.states,
+                state1)
         for k, v in traced.items():
             self.traced[k][slot] = v
         self._traced_cache = None          # hyperparameter row changed
@@ -547,66 +546,71 @@ class PlacementService:
 
     def step(self) -> List[PlacementJob]:
         """Advance every slot `gens_per_step` generations in one jitted
-        call; harvest and return newly finished jobs."""
+        call; harvest and return newly finished jobs.
+
+        Traced (`tracing.enabled()`), one `pool.step` span encloses the
+        leaf spans `pool.dispatch` (upload + enqueue of the step),
+        `pool.readback` (the host waiting for the step's result) and one
+        `pool.harvest` per finished job."""
         if not self.active.any():
             return []
-        n_active = int(self.active.sum())
-        traced_on = tracing.enabled()
-        if traced_on:
-            tracing.tracer().begin("pool.step", pool=self.label,
-                                   active=n_active)
+        tr = tracing.tracer()
         t_step = time.perf_counter()
-        # jnp.array copies: the numpy mirrors are mutated in place below
-        # and by submit(), and CPU jax may otherwise alias their buffers
-        # while the dispatched step is still consuming them
-        with self._blocking():
-            self.states, best = self._step_fn(
-                self._traced_dev(), self.states,
-                jnp.array(self.slot_seed), jnp.array(self.slot_gens))
-        self.total_steps += 1
-        self.useful_gens += int(self.active.sum()) * self.gens_per_step
-        self.slot_gens += self.gens_per_step
-        best = np.asarray(best)
-        metric = np.asarray(O.combined_metric(best))
-        if self._first_gen_ms is None:
-            # first generations actually served: the pool's cold-start
-            # latency (construction + first submit + first step, compiles
-            # included) -- the number the compile bench/CI budget watches
-            self._first_gen_ms = (time.perf_counter()
-                                  - self._created_at) * 1e3
-        finished = []
-        best_active = float("inf")
-        for slot in np.where(self.active)[0]:
-            job = self.slot_job[slot]
-            job.gens += self.gens_per_step
-            job.best_objs = best[slot]
-            job.metric = float(metric[slot])
-            # live convergence: one (gens, metric) point per step boundary
-            job.history.append((job.gens, job.metric))
-            best_active = min(best_active, job.metric)
-            hit_target = job.target is not None and job.metric <= job.target
-            if job.gens >= job.budget or hit_target:
-                self._harvest(slot, job)
-                finished.append(job)
-                self.active[slot] = False
-                self.slot_job[slot] = None
-                _M_HARVESTED.inc()
-                if traced_on:
-                    tracing.tracer().instant(
-                        "job.harvested", job.trace_id, slot=int(slot),
-                        gens=job.gens, metric=job.metric,
-                        hit_target=hit_target)
-        step_ms = (time.perf_counter() - t_step) * 1e3
-        self._step_hist.observe(step_ms)
-        _M_STEP_MS.observe(step_ms)
-        _M_STEPS.inc()
-        _M_GENS.inc(int(self.active.sum() + len(finished))
-                    * self.gens_per_step)
-        if best_active != float("inf"):
-            _M_BEST.set(best_active, pool=self.label)
-        if traced_on:
-            tracing.tracer().end("pool.step", pool=self.label,
-                                 harvested=len(finished))
+        with tr.span("pool.step", leaf=False, pool=self.label):
+            # jnp.array copies: the numpy mirrors are mutated in place
+            # below and by submit(), and CPU jax may otherwise alias their
+            # buffers while the dispatched step is still consuming them
+            with tr.span("pool.dispatch", pool=self.label), \
+                    self._blocking():
+                self.states, best = self._step_fn(
+                    self._traced_dev(), self.states,
+                    jnp.array(self.slot_seed), jnp.array(self.slot_gens))
+            self.total_steps += 1
+            self.useful_gens += int(self.active.sum()) * self.gens_per_step
+            self.slot_gens += self.gens_per_step
+            with tr.span("pool.readback", pool=self.label):
+                best = np.asarray(best)
+                metric = np.asarray(O.combined_metric(best))
+            if self._first_gen_ms is None:
+                # first generations actually served: the pool's cold-start
+                # latency (construction + first submit + first step,
+                # compiles included) -- the number the compile bench/CI
+                # budget watches
+                self._first_gen_ms = (time.perf_counter()
+                                      - self._created_at) * 1e3
+            finished = []
+            best_active = float("inf")
+            for slot in np.where(self.active)[0]:
+                job = self.slot_job[slot]
+                job.gens += self.gens_per_step
+                job.best_objs = best[slot]
+                job.metric = float(metric[slot])
+                # live convergence: one (gens, metric) point per step
+                # boundary
+                job.history.append((job.gens, job.metric))
+                best_active = min(best_active, job.metric)
+                hit_target = (job.target is not None
+                              and job.metric <= job.target)
+                if job.gens >= job.budget or hit_target:
+                    with tr.span("pool.harvest", job.trace_id,
+                                 pool=self.label, slot=int(slot)):
+                        self._harvest(slot, job)
+                    finished.append(job)
+                    self.active[slot] = False
+                    self.slot_job[slot] = None
+                    _M_HARVESTED.inc()
+                    if tracing.enabled():
+                        tracing.tracer().instant(
+                            "job.harvested", job.trace_id, slot=int(slot),
+                            gens=job.gens, metric=job.metric,
+                            hit_target=hit_target)
+            _M_STEP_MS.observe((time.perf_counter() - t_step) * 1e3,
+                               pool=self.label)
+            _M_STEPS.inc()
+            _M_GENS.inc(int(self.active.sum() + len(finished))
+                        * self.gens_per_step)
+            if best_active != float("inf"):
+                _M_BEST.set(best_active, pool=self.label)
         return finished
 
     def _harvest(self, slot: int, job: PlacementJob) -> None:
@@ -686,7 +690,9 @@ class PlacementService:
             compile_secs_total=round(self._meter.compile_secs, 3),
             persistent_cache_dir=compile_cache.enabled_dir(),
             # --- appended under schema_version 2 (observability) ---
-            step_ms_hist=self._step_hist.to_dict(),
+            # the registry's step histogram under this pool's label (pools
+            # of one label in one process share it)
+            step_ms_hist=_M_STEP_MS.to_dict(pool=self.label),
             convergence={
                 job.jid: list(job.history)[-CONVERGENCE_TAIL:]
                 for job in self.inflight()},

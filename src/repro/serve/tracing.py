@@ -16,10 +16,25 @@ this module answers the per-request one -- "where did job X spend its
   * timestamps are `time.monotonic()` (ordering/duration) with a wall
     clock alongside (correlation across processes).
 
+**Spans nest.**  `Tracer.span()` keeps a per-thread stack of the spans
+open on that thread; each span's end event carries its ``parent`` (the
+name of the enclosing span, or None) and ``cpu_ms`` (the thread's CPU
+milliseconds over the span, `time.thread_time()`): a long span with
+little CPU waited (on the device, a lock, or a host that did not run the
+process), one with as much CPU computed.  A *leaf* span (the default)
+also enters a `jax.profiler.TraceAnnotation` of its own name, so it
+lands in the profiler's host plane on the device trace's clock and the
+idle gaps of a device trace can be named after it.  Enclosing spans
+(``span(..., leaf=False)``: ``pool.step``) get no annotation: a trace
+reduction names each idle gap after the host event that overlaps it
+most, and an enclosing span would win every gap and hide its leaves.
+
 **Disabled is the default and costs one module-level branch.**  Call
-sites guard with ``if tracing.enabled():``; when off, no event object is
-ever built.  The bench `telemetry` section hard-gates the disabled-path
-overhead (`check_bench.py`).
+sites guard with ``if tracing.enabled():`` or go through `span()`, which
+tests the same flag and hands back a shared no-op context when off; when
+off, no event object and no annotation is ever built.  The bench
+`telemetry` section hard-gates the disabled-path overhead
+(`check_bench.py`).
 
 Exporters (all opt-in):
 
@@ -35,6 +50,7 @@ Exporters (all opt-in):
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
@@ -47,14 +63,12 @@ from typing import Any, Dict, IO, List, Optional, Tuple
 __all__ = [
     "TraceEvent", "Tracer", "tracer", "enabled", "enable", "disable",
     "maybe_enable_from_env", "new_trace_id", "TERMINAL_EVENTS",
-    "JOB_EVENTS", "write_chrome_trace",
+    "write_chrome_trace",
 ]
 
 # one terminal event per job, exactly -- gated by bench + tests
 TERMINAL_EVENTS = frozenset(
     {"job.harvested", "job.cancelled", "job.failed", "job.cache_hit"})
-JOB_EVENTS = frozenset(
-    {"job.submit", "job.queued", "job.admitted"}) | TERMINAL_EVENTS
 
 # ring capacities: ~100 bytes/event in-memory; 64k events / 4k traces
 # bounds a long-lived process at a few MB of trace state
@@ -110,6 +124,15 @@ class Tracer:
         self._by_trace: "OrderedDict[str, List[TraceEvent]]" = OrderedDict()
         self._sinks: List[IO[str]] = []
         self._t0 = time.monotonic()
+        self._local = threading.local()    # .open: this thread's span names
+
+    def _open_spans(self) -> List[str]:
+        """Names of the spans open on the calling thread, outermost
+        first."""
+        stack = getattr(self._local, "open", None)
+        if stack is None:
+            stack = self._local.open = []
+        return stack
 
     # ------------------------------------------------------------- record
 
@@ -160,29 +183,52 @@ class Tracer:
                                 tid=threading.get_ident(), attrs=attrs))
 
     class _Span:
-        __slots__ = ("_tracer", "_name", "_trace_id", "_attrs")
+        __slots__ = ("_tracer", "_name", "_trace_id", "_attrs", "_leaf",
+                     "_cpu0", "_annotation")
 
         def __init__(self, tracer: "Tracer", name: str,
-                     trace_id: Optional[str], attrs: Dict[str, Any]):
+                     trace_id: Optional[str], leaf: bool,
+                     attrs: Dict[str, Any]):
             self._tracer = tracer
             self._name = name
             self._trace_id = trace_id
+            self._leaf = leaf
             self._attrs = attrs
+            self._annotation = None
 
         def __enter__(self) -> "Tracer._Span":
+            self._cpu0 = time.thread_time()
             self._tracer.begin(self._name, self._trace_id, **self._attrs)
+            self._tracer._open_spans().append(self._name)
+            if self._leaf:
+                from jax.profiler import TraceAnnotation
+                self._annotation = TraceAnnotation(self._name)
+                self._annotation.__enter__()
             return self
 
         def __exit__(self, exc_type, exc, tb) -> None:
-            attrs = dict(self._attrs)
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
+            cpu_ms = 1e3 * (time.thread_time() - self._cpu0)
+            stack = self._tracer._open_spans()
+            stack.pop()
+            attrs = dict(self._attrs, parent=stack[-1] if stack else None,
+                         cpu_ms=round(cpu_ms, 3))
             if exc_type is not None:
                 attrs["error"] = exc_type.__name__
             self._tracer.end(self._name, self._trace_id, **attrs)
 
     def span(self, name: str, trace_id: Optional[str] = None,
-             **attrs: Any) -> "Tracer._Span":
-        """``with tracer().span("pool.step", pool=label): ...``"""
-        return Tracer._Span(self, name, trace_id, attrs)
+             leaf: bool = True, **attrs: Any):
+        """``with tracer().span("pool.dispatch", pool=label): ...``
+
+        A context that records a begin/end pair around its block (see the
+        module docstring for ``parent``, ``cpu_ms`` and what `leaf`
+        does).  While tracing is disabled it is a shared no-op context:
+        nothing is recorded and no profiler annotation is built."""
+        if not _ENABLED:
+            return _NO_SPAN
+        return Tracer._Span(self, name, trace_id, leaf, attrs)
 
     # -------------------------------------------------------------- query
 
@@ -249,6 +295,7 @@ class Tracer:
 
 
 _TRACER = Tracer()
+_NO_SPAN = contextlib.nullcontext()
 
 
 def tracer() -> Tracer:

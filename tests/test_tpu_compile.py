@@ -110,3 +110,38 @@ def test_evaluate_population_compiles(chip, vu11p_problem, monkeypatch,
         monkeypatch.undo()
         jax.clear_caches()
     assert "tpu_custom_call" in text
+
+
+def test_step_program_keeps_the_kernel_names_and_their_phases(
+        chip, small_problem, monkeypatch):
+    """The pool's whole step program (at the 6-unit part's widths, to keep
+    the compile short): the kernels keep the instruction names the
+    benchmark's roofline reads (`%wirelength2_pallas`, `%maxbbox_pallas`),
+    and their `op_name`, which the profiler reports as `tf_op`, holds the
+    phase the benchmark charges them to."""
+    import re
+
+    from repro.core.nsga2 import NSGA2Config
+    from repro.kernels import ops
+    from repro.serve.placement_service import PlacementService
+
+    svc = PlacementService(small_problem, NSGA2Config(pop_size=8),
+                           n_slots=2, gens_per_step=1)
+    args = jax.tree.map(lambda a: _spec(chip, a.shape, a.dtype),
+                        (svc._traced_dev(), svc.states,
+                         jnp.array(svc.slot_seed), jnp.array(svc.slot_gens)))
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    try:
+        text = svc._step_fn.lower(*args).compile().as_text()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    kernels = {m.group(1): m.group(2).split("/") for m in re.finditer(
+        r'\n\s+(?:ROOT )?(%\w+_pallas)\.\d+ = [^\n]*op_name="([^"]*)"',
+        text)}
+    assert set(kernels) == {"%wirelength2_pallas", "%maxbbox_pallas",
+                            "%domination_pallas"}
+    assert "evaluate" in kernels["%wirelength2_pallas"]
+    assert "evaluate" in kernels["%maxbbox_pallas"]
+    assert "rank" in kernels["%domination_pallas"]
