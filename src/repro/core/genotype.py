@@ -82,6 +82,16 @@ def allocate_counts(genes: jnp.ndarray, caps: jnp.ndarray,
     return base + give
 
 
+def _col_lookup(table, col: jnp.ndarray) -> jnp.ndarray:
+    """`table[col]` for a short per-column table, as a one-hot select summed
+    over the C columns in the table's own dtype: no gather, and exact, since
+    each row keeps one entry. (A matmul would round through bfloat16.)"""
+    table = jnp.asarray(table)
+    hit = col[:, None] == jnp.arange(table.shape[0])[None, :]
+    return jnp.sum(jnp.where(hit, table[None, :], 0), axis=-1,
+                   dtype=table.dtype)
+
+
 def _decode_type(geom: TypeGeom, dist: jnp.ndarray, loc: jnp.ndarray
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Decode one hard-block type to physical chain-member coordinates.
@@ -94,7 +104,10 @@ def _decode_type(geom: TypeGeom, dist: jnp.ndarray, loc: jnp.ndarray
 
     bounds = jnp.cumsum(counts)                       # exclusive upper bounds
     chain_idx = jnp.arange(N)
-    col = jnp.searchsorted(bounds, chain_idx, side="right").astype(jnp.int32)
+    # one dense comparison over the C (<= 50) columns: the default method's
+    # loop of per-row gathers is the slowest part of a decode on the TPU
+    col = jnp.searchsorted(bounds, chain_idx, side="right",
+                           method="compare_all").astype(jnp.int32)
     col = jnp.clip(col, 0, geom.n_cols - 1)
 
     # within-column order by location gene: single global sort on (col, loc)
@@ -103,11 +116,11 @@ def _decode_type(geom: TypeGeom, dist: jnp.ndarray, loc: jnp.ndarray
     order = jnp.argsort(key)
     col_s = col[order]
     loc_s = locc[order]
-    col_start = (bounds - counts)[col_s]
+    col_start = _col_lookup(bounds - counts, col_s)
     rank_s = jnp.arange(N) - col_start                # rank within column
 
     # spread slack slots according to location genes, monotone within column
-    slack_sites = ((caps - counts) * L)[col_s].astype(jnp.float32)
+    slack_sites = _col_lookup((caps - counts) * L, col_s).astype(jnp.float32)
     off = jnp.floor(loc_s * (slack_sites + 1.0))
     off = jnp.minimum(off, slack_sites)
     off = _seg_cummax(off, col_s)                     # keep packing legal
@@ -117,10 +130,10 @@ def _decode_type(geom: TypeGeom, dist: jnp.ndarray, loc: jnp.ndarray
 
     member = jnp.arange(L)[None, :]
     site = ystart[:, None] + member                   # sub-column site index
-    parity = jnp.asarray(geom.col_parity)[col][:, None]
+    parity = _col_lookup(geom.col_parity, col)[:, None]
     phys_row = site * geom.site_step + parity
     y = phys_row.astype(jnp.float32) * geom.row_pitch
-    x = jnp.asarray(geom.col_x)[col][:, None] * jnp.ones((1, L), jnp.float32)
+    x = _col_lookup(geom.col_x, col)[:, None] * jnp.ones((1, L), jnp.float32)
     return x, y
 
 
