@@ -29,6 +29,8 @@ import json
 import sys
 from pathlib import Path
 
+import numpy as np
+
 ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
@@ -37,13 +39,15 @@ from bench import generator, reference  # noqa: E402
 from bench import run as B  # noqa: E402
 
 
-def readings(bench, cell, config, mix, seeds, seconds, dump=None) -> int:
+def readings(bench, cell, config, mix, algo, seeds, seconds,
+             dump=None) -> int:
     prob = reference.Problem(config["device"])
     metrics = B.cell_metrics(bench, cell, False)
     program, control, empty, ambiguous = [], [], [], []
     for seed in seeds:
-        out = B.run_cell(cell, config, mix, seed, seconds, False, metrics)
-        ctl = B.compare(prob, out["copies"], control=True)
+        out = B.run_cell(cell, config, mix, algo, seed, seconds, False,
+                         metrics)
+        ctl = B.compare(algo, prob, out["copies"], control=True)
         print(json.dumps({"seed": seed, "correct": out["line"]["correct"],
                           "program": out["numbers"], "control": ctl,
                           "metrics": out["line"]["metrics"],
@@ -54,7 +58,7 @@ def readings(bench, cell, config, mix, seeds, seconds, dump=None) -> int:
         program.append(out["numbers"]["objective_gap"])
         control.append(ctl["objective_gap"])
         if dump:
-            ambiguous += ambiguous_members(prob, seed, out["copies"])
+            ambiguous += ambiguous_members(algo, prob, seed, out["copies"])
     if dump:
         Path(dump).parent.mkdir(parents=True, exist_ok=True)
         Path(dump).write_text(json.dumps(ambiguous))
@@ -69,28 +73,27 @@ def readings(bench, cell, config, mix, seeds, seconds, dump=None) -> int:
     return 0
 
 
-def ambiguous_members(prob, seed, copies):
-    """The members of the checked final populations that the reference
-    leaves out of `objective_gap`, with their genotypes and the program's
-    objectives."""
+def ambiguous_members(algo, prob, seed, copies):
+    """The genotypes of the checked jobs (the algorithm module's
+    `candidates`) that the reference leaves out of `objective_gap`, with
+    the program's objectives."""
     out = []
     for j, job in enumerate(copies):
         if job is None:
             continue
-        for k in range(len(job["objs"])):
-            g = reference.member(job["pop"], k)
+        for k, (g, objs) in enumerate(algo.candidates(job)):
             if reference.decode(prob, g)[2]:
                 out.append({"seed": seed, "job": j, "member": k,
-                            "objs": job["objs"][k].tolist(),
-                            **{part: [a.tolist() for a in g[part]]
+                            "objs": np.asarray(objs).tolist(),
+                            **{part: [np.asarray(a).tolist() for a in g[part]]
                                for part in ("dist", "loc", "perm")}})
     return out
 
 
-def sweep(bench, cell, config, mix, seed, seconds, rates) -> None:
+def sweep(bench, cell, config, mix, algo, seed, seconds, rates) -> None:
     metrics = B.cell_metrics(bench, cell, False)
     for rate in rates:
-        out = B.run_cell(cell, config, dict(mix, rate_jobs_per_s=rate),
+        out = B.run_cell(cell, config, dict(mix, rate_jobs_per_s=rate), algo,
                          seed, seconds, False, metrics)
         m = out["line"]["metrics"]
         print(json.dumps({
@@ -116,15 +119,15 @@ def main(argv=None) -> int:
     ap.add_argument("--traffic", default="")
     ap.add_argument("--dump", default="")
     args = ap.parse_args(argv)
-    bench, cell, config, mix = B.load_cell(args.workload)
+    bench, cell, config, mix, algo = B.load_cell(args.workload)
     if args.traffic:
         mix = generator.load(args.traffic)
     B.start_chip(cell)
     if args.mode == "readings":
-        return readings(bench, cell, config, mix,
+        return readings(bench, cell, config, mix, algo,
                         [int(s) for s in args.seeds.split(",")],
                         args.seconds, args.dump)
-    sweep(bench, cell, config, mix, args.seed, args.seconds,
+    sweep(bench, cell, config, mix, algo, args.seed, args.seconds,
           [float(r) for r in args.rates.split(",")])
     return 0
 

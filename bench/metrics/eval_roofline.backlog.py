@@ -5,8 +5,11 @@ The least time of the work they did in the traced slice
 for this chip) over their device time in the trace.  Kernels are found by
 their instruction names, `%wirelength2_pallas.<n>` (Eq. 1) and
 `%maxbbox_pallas.<n>` (Eq. 2).  A launch inside the pool's step program
-evaluates slots x population placements (vacant slots too: the kernel runs
-them); a launch in any other program, a job's init, one population.
+evaluates slots x the placements a job evaluates per generation (the
+algorithm module's `evals_per_gen`, as the pools report it; vacant slots
+too: the kernel runs them); a launch in any other program, a job's init,
+that count for one job.  Pools that evaluate different counts give no reading:
+the trace does not say which pool a launch served.
 """
 from bench import reference, roofline
 
@@ -15,16 +18,17 @@ STEP = "jit__step"
 
 
 def read(run):
-    if run.trace is None or run.peaks is None:
+    counts = {p["evals_per_gen"] for p in run.pools}
+    if run.trace is None or run.peaks is None or len(counts) != 1:
         return None
     s = run.config["search"]
-    pop = s["algorithm"]["pop_size"]
+    (evals,) = counts
     prob = reference.Problem(run.config["device"])
     seconds = 0.0
     flops = nbytes = 0.0
     for key, (secs, count) in run.trace["ops"].items():
         module, op = key.split("/", 1)
-        per_launch = pop * (s["n_slots"] if module == STEP else 1)
+        per_launch = evals * (s["n_slots"] if module == STEP else 1)
         if op.startswith(WIRELENGTH + "."):
             f, b = roofline.wirelength(per_launch * count, prob.n_nets)
         elif op.startswith(BBOX + "."):

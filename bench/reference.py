@@ -14,13 +14,16 @@ Table II, as the program models them), then
   * checks legality from the coordinates alone: every chain on a column of
     its type, cascade members on consecutive sites, no site used twice, all
     inside the rectangle, every mapping a permutation;
-  * checks the NSGA-II selection by brute force: the final population must
-    be ordered by non-dominated rank, and the champion must be the member
-    with the least combined metric (wl^2 x bbox).
+  * gives the gap between the objectives the program reported for one
+    genotype and those of its reference decode (`genotype_gap`).
+
+What an algorithm's final state must satisfy beyond that (a population's
+order, how its champion is chosen) is checked by the configuration's
+algorithm module, `bench/algorithms/<algorithm>.py`.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -299,100 +302,24 @@ def illegal(prob: Problem, g: Dict, bx: np.ndarray, by: np.ndarray
     return bad
 
 
-# --------------------------------------------------------------- selection
+# ------------------------------------------------------- one genotype
 
-def domination(objs: np.ndarray) -> np.ndarray:
-    """dom[i, j]: member i dominates member j (minimisation)."""
-    a, b = objs[:, None, :], objs[None, :, :]
-    return np.all(a <= b, axis=-1) & np.any(a < b, axis=-1)
-
-
-def nondominated_ranks(objs: np.ndarray) -> np.ndarray:
-    """Pareto front index of every member (0 = best), by peeling fronts."""
-    dom = domination(objs)
-    rank = np.full(len(objs), -1)
-    r = 0
-    while np.any(rank < 0):
-        left = rank < 0
-        front = left & ~np.any(dom & left[:, None], axis=0)
-        rank[front] = r
-        r += 1
-    return rank
-
-
-def rank_inversions(objs: np.ndarray) -> int:
-    """Pairs i < j in which member j dominates member i: an NSGA-II
-    population leaves its (mu + lambda) truncation sorted by rank, so a
-    correct final population has none."""
-    return int(np.triu(domination(objs).T, k=1).sum())
-
-
-def champion_index(objs: np.ndarray) -> int:
-    """The member with the least combined metric wl^2 x bbox, taken in the
-    population's own float32 objectives (first on ties)."""
-    o = np.asarray(objs, np.float32)
-    return int(np.argmin(o[:, 0] * o[:, 1]))
-
-
-# --------------------------------------------------------------- the check
-
-def member(pop: Dict, k: int) -> Dict:
-    """Member k of a population genotype (leading population axis)."""
-    return {part: tuple(np.asarray(a)[k] for a in pop[part])
-            for part in ("dist", "loc", "perm")}
-
-
-def check_job(prob: Problem, pop: Dict, objs: np.ndarray, champion: Dict,
-              champion_objs: np.ndarray, control: bool = False) -> Dict:
-    """Compare one job's final population and reported champion with the
-    reference.  `control=True` puts the bfloat16 reference in the place of
-    the program's objectives.  Returns the numbers that `correct` weighs."""
-    objs = np.asarray(objs, np.float32)
-    champion_objs = np.asarray(champion_objs, np.float32)
-    out = {"objective_gap": 0.0, "illegal_placements": 0,
-           "selection_misses": 0, "rank_inversions": 0,
-           "members_checked": 0, "members_ambiguous": 0}
-    sizes = prob.sizes()
-    shaped = all(np.shape(champion[part][t]) == (sizes[part][t],)
-                 for part in sizes for t in TYPES)
-    if not shaped or objs.ndim != 2 or objs.shape[1] != 2:
-        out["illegal_placements"] = 1
-        out["selection_misses"] = 1
-        return out
-    candidates = [(member(pop, k), objs[k]) for k in range(len(objs))]
-    candidates.append((champion, champion_objs))
-    for g, reported in candidates:
-        bx, by, ambiguous = decode(prob, g)
-        if illegal(prob, g, bx, by):
-            out["illegal_placements"] += 1
-        if ambiguous:
-            out["members_ambiguous"] += 1
-            continue
-        want = objectives(prob, bx, by)
-        got = control_objectives(prob, bx, by) if control else reported
-        gap = float(np.max(np.abs(np.asarray(got, np.float64) - want)
-                           / np.maximum(want, 1e-30)))
-        if not np.isfinite(gap):            # NaN or inf objectives fail
-            gap = np.inf
-        out["objective_gap"] = max(out["objective_gap"], gap)
-        out["members_checked"] += 1
-    best = member(pop, champion_index(objs))
-    same = all(np.array_equal(np.asarray(best[p][t]),
-                              np.asarray(champion[p][t]))
-               for p in ("dist", "loc", "perm") for t in TYPES)
-    if not (same and np.array_equal(champion_objs,
-                                    objs[champion_index(objs)])):
-        out["selection_misses"] = 1
-    out["rank_inversions"] = rank_inversions(objs)
-    return out
-
-
-def merge(results: Sequence[Dict]) -> Dict:
-    """Fold per-job numbers: gaps by their maximum, counts by their sum."""
-    out = {"objective_gap": 0.0, "illegal_placements": 0,
-           "selection_misses": 0, "rank_inversions": 0,
-           "members_checked": 0, "members_ambiguous": 0}
-    for r in results:
-        for k, v in r.items():
-            out[k] = max(out[k], v) if k == "objective_gap" else out[k] + v
-    return out
+def genotype_gap(prob: Problem, g: Dict, reported, control: bool = False
+                 ) -> Tuple[bool, Optional[float]]:
+    """One genotype against the objectives the program reported for it:
+    (illegal, gap).  `illegal` when its reference decode breaks a
+    constraint; `gap` the widest relative gap between `reported` and the
+    float64 Eqs. 1-2 of that decode (inf for NaN or inf objectives), or
+    None when a float32 rounding could change the decode.  `control=True`
+    puts the bfloat16 reference in the place of `reported`."""
+    bx, by, ambiguous = decode(prob, g)
+    bad = bool(illegal(prob, g, bx, by))
+    if ambiguous:
+        return bad, None
+    want = objectives(prob, bx, by)
+    got = control_objectives(prob, bx, by) if control else reported
+    gap = float(np.max(np.abs(np.asarray(got, np.float64) - want)
+                       / np.maximum(want, 1e-30)))
+    if not np.isfinite(gap):            # NaN or inf objectives fail
+        gap = np.inf
+    return bad, gap

@@ -3,8 +3,10 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (`BENCHMARK.json` "workloads") names a configuration
-(`bench/configs/<config>.json`) and a traffic mix (`bench/traffic/<mix>.json`).
-The run
+(`bench/configs/<config>.json`) and a traffic mix (`bench/traffic/<mix>.json`);
+the configuration names its search algorithm, whose module
+`bench/algorithms/<algorithm>.py` builds each job's config and checks its
+final state.  The run
 
   1. refuses to start unless JAX sees TPUs, as many as the cell asks for;
   2. sets up: keeps JAX's persistent compilation cache in `<checkout>/.jax_cache`,
@@ -18,7 +20,8 @@ The run
      for its first `TRACE_S` seconds;
   4. drains every job that was due in the window, reads the chip's peak
      memory, frees the program's state, and checks the answers of a sample
-     of those jobs (drawn from the seed) against `bench/reference.py`;
+     of those jobs (drawn from the seed) against `bench/reference.py`,
+     through the algorithm's module;
   5. reads each metric of the cell with its reader `bench/metrics/<name>.py`
      (`--trace 0`: the end-to-end metrics, `--trace 1`: the per-layer ones).
 
@@ -58,11 +61,8 @@ MAX_QUEUE = 1 << 20          # open loops never meet front-end backpressure
 RAMP_S = 30.0                # closed loop: at most this long to fill slots
 DRAIN_S = 90.0               # at most this long for the window's jobs
 TRACE_S = 10.0               # profiled slice: one harvest wave or more
-CHECK_SHARE = 0.25           # share of jobs whose final population is kept
+CHECK_SHARE = 0.25           # share of jobs whose final state is kept
 CHECK_JOBS = 24              # jobs compared with the reference per run
-LIMITS = {"objective_gap": reference.OBJECTIVE_GAP_LIMIT,
-          "illegal_placements": 0, "selection_misses": 0,
-          "rank_inversions": 0, "unchecked_jobs": 0}
 
 
 @dataclasses.dataclass
@@ -78,7 +78,7 @@ class JobRecord:
     result: Any = None
     error: Optional[str] = None
     window: bool = False               # due (or submitted) in the window
-    check: bool = False                # final population kept for the check
+    check: bool = False                # final state kept for the check
 
     @property
     def ok(self) -> bool:
@@ -116,7 +116,8 @@ class Run:
 # -------------------------------------------------------------- the cell
 
 def load_cell(name: str, root: Path = ROOT):
-    """(benchmark, cell, configuration, mix) for a workload name."""
+    """(benchmark, cell, configuration, mix, algorithm module) for a
+    workload name."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -126,7 +127,7 @@ def load_cell(name: str, root: Path = ROOT):
     entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     config = json.loads((root / entry["file"]).read_text())
     mix = generator.load(root / "bench" / "traffic" / f"{cell['traffic']}.json")
-    return bench, cell, config, mix
+    return bench, cell, config, mix, algorithm(config["algorithm"], root)
 
 
 def cell_metrics(bench: Dict, cell: Dict, trace: bool) -> List[Dict]:
@@ -135,14 +136,55 @@ def cell_metrics(bench: Dict, cell: Dict, trace: bool) -> List[Dict]:
             if cell["name"] in m.get("workloads", [cell["name"]])]
 
 
-def reader(name: str, root: Path = ROOT) -> Callable[[Run], Optional[float]]:
-    """The `read(run)` function of `bench/metrics/<name>.py`."""
-    path = root / "bench" / "metrics" / f"{name}.py"
+def _module(path: Path, kind: str):
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+        f"bench_{kind}_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str, root: Path = ROOT) -> Callable[[Run], Optional[float]]:
+    """The `read(run)` function of `bench/metrics/<name>.py`."""
+    return _module(root / "bench" / "metrics" / f"{name}.py", "metric").read
+
+
+def algorithm(name: str, root: Path = ROOT):
+    """The module `bench/algorithms/<name>.py` of a search algorithm.  It
+    holds what the harness needs of that algorithm:
+
+      ALGO                      the service's name for it (`JobRequest.algo`)
+      job_config(algorithm, hyper)
+                                the program's config object for one job, from
+                                the configuration's `search.algorithm` and the
+                                job's drawn hyperparameters
+      evals_per_gen(cfg, prob)  placements one job evaluates per generation
+                                (`prob`: the `bench/reference.py` Problem)
+      host_copy(state, result)  what the check needs of one job, on the host,
+                                from its harvested final state and its result
+      candidates(copy)          the (genotype, reported objectives) pairs the
+                                check compares with the reference
+      check(prob, copy, control)
+                                one job's numbers: at least objective_gap,
+                                illegal_placements, members_checked and
+                                members_ambiguous, and those of LIMITS
+      merge(results)            those numbers over the sampled jobs
+      LIMITS                    the limits of the algorithm's own numbers
+
+    A configuration whose algorithm has no module exits here, before JAX
+    is imported or a chip is asked for."""
+    path = root / "bench" / "algorithms" / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: algorithm {name!r} has no module "
+                         f"{path.relative_to(root)}; nothing measured")
+    return _module(path, "algorithm")
+
+
+def limits(algo) -> Dict:
+    """The limit of every number `correct` compares, in the order the
+    result line gives them: the reference's, then the algorithm's own."""
+    return {"objective_gap": reference.OBJECTIVE_GAP_LIMIT,
+            "illegal_placements": 0, **algo.LIMITS, "unchecked_jobs": 0}
 
 
 def require_chips(count: int):
@@ -158,25 +200,16 @@ def require_chips(count: int):
     return devs
 
 
-def algorithm_config(config: Dict, hyper: Dict):
-    """The program's algorithm config for one job of this configuration."""
-    if config["algorithm"] != "nsga2":
-        raise SystemExit(f"bench: no reference check for algorithm "
-                         f"{config['algorithm']!r}")
-    from repro.core.nsga2 import NSGA2Config
-    return NSGA2Config(**config["search"]["algorithm"], **hyper)
-
-
-# ------------------------------------------------------- final populations
+# ------------------------------------------------------------ final states
 
 class Capture:
-    """Keeps the final population of the jobs chosen for the check.
+    """Keeps the final state of the jobs chosen for the check.
 
     The pool harvests a job by handing its final state to
     `core.portfolio.best_genotype`; the wrapper keeps a reference to that
     state (already on the device: no copy, no extra work) for the jobs
-    whose config object it watches, so the reference can re-derive the
-    champion and re-check the whole population."""
+    whose config object it watches, so the algorithm's check can re-derive
+    the champion from the whole state."""
 
     def __init__(self):
         self.watch: Dict[int, JobRecord] = {}
@@ -284,12 +317,14 @@ def _checked(seed: int, index: int, share: float) -> bool:
 class Harness:
     """Set-up, window and drain of one run, on one asyncio loop."""
 
-    def __init__(self, run: Run, seed: int, seconds: float, trace: bool,
-                 check_share: float = CHECK_SHARE):
+    def __init__(self, run: Run, algo, prob: reference.Problem, seed: int,
+                 seconds: float, trace: bool, check_share: float = CHECK_SHARE):
         from repro.serve.scheduler import PlacementScheduler
-        self.run, self.seed, self.seconds = run, seed, seconds
+        self.run, self.algo, self.seed, self.seconds = run, algo, seed, seconds
         self.trace, self.check_share = trace, check_share
         search = run.config["search"]
+        self.evals_per_gen = algo.evals_per_gen(
+            algo.job_config(search["algorithm"], {}), prob)
         self.device = run.config["device"]["name"]
         self.sch = PlacementScheduler(n_slots=search["n_slots"],
                                       gens_per_step=search["gens_per_step"])
@@ -302,10 +337,11 @@ class Harness:
     def record(self, job: Dict, due: Optional[float] = None,
                check: bool = False) -> JobRecord:
         from repro.serve.api import JobRequest
-        cfg = algorithm_config(self.run.config, job["hyper"])
+        cfg = self.algo.job_config(self.run.config["search"]["algorithm"],
+                                   job["hyper"])
         rec = JobRecord(job=job, due=due, check=check, request=JobRequest(
-            device=self.device, cfg=cfg, seed=job["seed"],
-            budget=job["budget"]))
+            device=self.device, cfg=cfg, algo=self.algo.ALGO,
+            seed=job["seed"], budget=job["budget"]))
         if check:
             self.capture.watch[id(cfg)] = rec
         self.run.jobs.append(rec)
@@ -323,7 +359,7 @@ class Harness:
     def pool_counts(self) -> List[Dict]:
         return [{"label": label, "steps": svc.total_steps,
                  "useful_gens": svc.useful_gens,
-                 "pop": self.run.config["search"]["algorithm"]["pop_size"],
+                 "evals_per_gen": self.evals_per_gen,
                  "active": int(svc.active.sum()), "slots": svc.n_slots}
                 for label, svc in self.sch.pools().items()]
 
@@ -494,8 +530,8 @@ class Harness:
 # --------------------------------------------------------------- the check
 
 def sample(run: Run, seed: int, n: int = CHECK_JOBS) -> List[JobRecord]:
-    """Up to `n` window jobs with a kept final population, drawn from the
-    seed, always with the largest budget among them."""
+    """Up to `n` window jobs with a kept final state, drawn from the seed,
+    always with the largest budget among them."""
     import numpy as np
     pool = [r for r in run.window_jobs if r.check and r.ok]
     if len(pool) <= n:
@@ -508,31 +544,23 @@ def sample(run: Run, seed: int, n: int = CHECK_JOBS) -> List[JobRecord]:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def host_copies(capture: Capture, recs: List[JobRecord]) -> List[Dict]:
-    """Final populations and champions of the sampled jobs, on the host."""
-    import numpy as np
+def host_copies(algo, capture: Capture, recs: List[JobRecord]
+                ) -> List[Optional[Dict]]:
+    """The algorithm's host copy of each sampled job; None for a job whose
+    final state was not captured."""
     out = []
     for r in recs:
         state = capture.states.get(r.job["index"])
-        if state is None:
-            out.append(None)
-            continue
-        out.append({
-            "pop": {p: tuple(np.asarray(a) for a in state["pop"][p])
-                    for p in ("dist", "loc", "perm")},
-            "objs": np.asarray(state["objs"]),
-            "champion": r.result.genotype,
-            "champion_objs": np.asarray(r.result.best_objs)})
+        out.append(None if state is None else algo.host_copy(state, r.result))
     return out
 
 
-def compare(prob: reference.Problem, jobs: List[Optional[Dict]],
+def compare(algo, prob: reference.Problem, jobs: List[Optional[Dict]],
             control: bool = False) -> Dict:
     """The numbers that decide `correct`, over the sampled jobs."""
-    results = [reference.check_job(prob, j["pop"], j["objs"], j["champion"],
-                                   j["champion_objs"], control=control)
+    results = [algo.check(prob, j, control=control)
                for j in jobs if j is not None]
-    out = reference.merge(results)
+    out = algo.merge(results)
     out["unchecked_jobs"] = sum(j is None for j in jobs) + (
         0 if results and out["members_checked"] else 1)
     out["jobs_checked"] = len(results)
@@ -541,8 +569,8 @@ def compare(prob: reference.Problem, jobs: List[Optional[Dict]],
 
 # ------------------------------------------------------------------ a run
 
-def run_cell(cell: Dict, config: Dict, mix: Dict, seed: int, seconds: float,
-             trace: bool, metrics: List[Dict],
+def run_cell(cell: Dict, config: Dict, mix: Dict, algo, seed: int,
+             seconds: float, trace: bool, metrics: List[Dict],
              check_share: float = CHECK_SHARE,
              check_jobs: int = CHECK_JOBS) -> Dict:
     """Set up, measure, drain and check one run; returns the result line."""
@@ -550,10 +578,11 @@ def run_cell(cell: Dict, config: Dict, mix: Dict, seed: int, seconds: float,
 
     from repro.serve import tracing
     run = Run(cell=cell, config=config, mix=mix)
+    prob = reference.Problem(config["device"])
     if trace:
         tracing.tracer().clear()
         tracing.enable()
-    harness = Harness(run, seed, seconds, trace, check_share)
+    harness = Harness(run, algo, prob, seed, seconds, trace, check_share)
     try:
         asyncio.run(harness.main())
         if trace:
@@ -563,7 +592,8 @@ def run_cell(cell: Dict, config: Dict, mix: Dict, seed: int, seconds: float,
         device = {"platform": dev.platform, "kind": dev.device_kind,
                   "count": jax.device_count(),
                   "memory_peak_bytes": mem.get("peak_bytes_in_use")}
-        copies = host_copies(harness.capture, sample(run, seed, check_jobs))
+        copies = host_copies(algo, harness.capture,
+                             sample(run, seed, check_jobs))
     finally:
         harness.capture.close()
         harness.clock.close()
@@ -572,8 +602,7 @@ def run_cell(cell: Dict, config: Dict, mix: Dict, seed: int, seconds: float,
     del harness
     gc.collect()
 
-    prob = reference.Problem(config["device"])
-    numbers = compare(prob, copies)
+    numbers = compare(algo, prob, copies)
     attempted = len(run.window_jobs)
     failed = sum(not r.ok for r in run.window_jobs)
     if trace:
@@ -588,9 +617,9 @@ def run_cell(cell: Dict, config: Dict, mix: Dict, seed: int, seconds: float,
         if v is not None:
             values[m["name"]] = {"value": v, "unit": m["unit"]}
     checks = {k: {"value": numbers[k], "limit": lim}
-              for k, lim in LIMITS.items()}
+              for k, lim in limits(algo).items()}
     line = {"correct": failed == 0 and all(
-                numbers[k] <= lim for k, lim in LIMITS.items()),
+                c["value"] <= c["limit"] for c in checks.values()),
             "attempted": attempted, "failed": failed, "metrics": values,
             "device": device}
     if trace:
@@ -651,9 +680,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    bench, cell, config, mix = load_cell(args.workload)
+    bench, cell, config, mix, algo = load_cell(args.workload)
     start_chip(cell)
-    out = run_cell(cell, config, mix, args.seed, args.seconds,
+    out = run_cell(cell, config, mix, algo, args.seed, args.seconds,
                    bool(args.trace), cell_metrics(bench, cell, args.trace))
     emit(out)
     return 0

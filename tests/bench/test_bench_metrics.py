@@ -40,8 +40,8 @@ def a_run(**kw):
 
 def test_rates_are_over_the_whole_window():
     run = a_run(seconds=20.0, pools=[
-        {"useful_gens": 1000, "pop": 64, "steps": 40},
-        {"useful_gens": 10, "pop": 8, "steps": 10}])
+        {"useful_gens": 1000, "evals_per_gen": 64, "steps": 40},
+        {"useful_gens": 10, "evals_per_gen": 8, "steps": 10}])
     assert B.reader("evals_per_s")(run) == (1000 * 64 + 10 * 8) / 20.0
     assert B.reader("step_ms.backlog")(run) == 1e3 * 20.0 / 50
     assert B.reader("evals_per_s")(a_run(seconds=0.0)) is None

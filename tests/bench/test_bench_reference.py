@@ -2,7 +2,8 @@
 
 The reference imports nothing from `src/repro`; these tests tie it to the
 program: same geometry and netlist, the same decode bit for bit, Eqs. 1-2
-within float32 rounding, and the same selection rules.
+within float32 rounding.  The NSGA-II selection rules are tied to the
+program in `test_bench_nsga2.py`.
 """
 import json
 import sys
@@ -17,7 +18,6 @@ sys.path.insert(0, str(ROOT))
 
 from bench import reference as R  # noqa: E402
 from repro.core import genotype as G  # noqa: E402
-from repro.core import nsga2, portfolio  # noqa: E402
 from repro.core import objectives as O  # noqa: E402
 from repro.fpga import device, netlist  # noqa: E402
 
@@ -96,43 +96,6 @@ def test_control_misses_the_limit(pair):
         got = R.control_objectives(ref, rx, ry)
         gaps.append(np.max(np.abs(got - want) / want))
     assert min(gaps) > 10 * R.OBJECTIVE_GAP_LIMIT
-
-
-def test_ranking_and_selection_match_nsga2(pair):
-    prog, ref = pair
-    cfg = nsga2.NSGA2Config(pop_size=16)
-    st = nsga2.init_state(prog, jax.random.PRNGKey(1), cfg)
-    for k in range(5):
-        st = nsga2.step(prog, cfg, st, jax.random.PRNGKey(10 + k))
-    objs = np.asarray(st["objs"])
-    want = np.asarray(nsga2.nondominated_rank(st["objs"]))
-    assert np.array_equal(R.nondominated_ranks(objs), want)
-    assert R.rank_inversions(objs) == 0
-    shuffled = objs[np.random.default_rng(0).permutation(len(objs))]
-    assert R.rank_inversions(shuffled) > 0 or \
-        len(set(R.nondominated_ranks(objs))) == 1
-    champ, champ_objs = portfolio.best_genotype(prog, "nsga2", st, cfg)
-    out = R.check_job(ref, host(st["pop"]), objs, host(champ),
-                      np.asarray(champ_objs))
-    assert out["selection_misses"] == 0 and out["rank_inversions"] == 0
-    assert out["illegal_placements"] == 0
-    assert out["objective_gap"] < R.OBJECTIVE_GAP_LIMIT
-    # a champion that is not the least wl^2 x bbox member is a miss
-    other = R.member(host(st["pop"]), len(objs) - 1)
-    out = R.check_job(ref, host(st["pop"]), objs, other, objs[-1])
-    assert out["selection_misses"] == 1
-
-
-def test_a_nan_objective_is_an_infinite_gap(pair):
-    prog, ref = pair
-    cfg = nsga2.NSGA2Config(pop_size=8)
-    st = nsga2.init_state(prog, jax.random.PRNGKey(2), cfg)
-    objs = np.array(st["objs"])
-    objs[3, 0] = np.nan
-    champ, champ_objs = portfolio.best_genotype(prog, "nsga2", st, cfg)
-    out = R.check_job(ref, host(st["pop"]), objs, host(champ),
-                      np.asarray(champ_objs))
-    assert out["objective_gap"] == np.inf
 
 
 # RAMB18 distribution genes of a member the chip decoded differently (VU11P,

@@ -74,7 +74,8 @@ def test_eval_roofline_arithmetic_on_a_built_trace(tmp_path):
     config = B.load_cell("vu11p_nsga2.backlog")[2]
     peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     run = B.Run(cell={}, config=config, mix={}, peaks=peaks,
-                trace=trace_reduce.reduce(tmp_path))
+                trace=trace_reduce.reduce(tmp_path),
+                pools=[{"evals_per_gen": 64}])
     # one Eq. 1 launch in the step program: 8 slots x 64 candidates
     want = 4 * 8 * 64 * (5 * 1999 + 1) / 819e9 / 1e-6
     assert B.reader("eval_roofline.backlog")(run) == pytest.approx(
@@ -110,9 +111,10 @@ def test_a_chip_trace_reduces_to_one_busy_step(chip_trace):
 
 
 def test_chip_trace_metrics(chip_trace):
-    _, cell, config, mix = B.load_cell("vu11p_nsga2.backlog")
+    _, cell, config, mix, _ = B.load_cell("vu11p_nsga2.backlog")
     run = B.Run(cell=cell, config=config, mix=mix, trace=chip_trace,
-                peaks=B.peaks_for("TPU v5 lite"))
+                peaks=B.peaks_for("TPU v5 lite"),
+                pools=[{"evals_per_gen": 64}])
     assert B.reader("step_device_ms.backlog")(run) == \
         pytest.approx(157.536483)
     assert 0 <= B.reader("device_idle_share.backlog")(run) < 0.01
